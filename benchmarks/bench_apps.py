@@ -35,7 +35,7 @@ import os
 import subprocess
 import sys
 
-from .common import emit
+from .common import cpu_child_env, emit, mark_cpu
 
 _CHILD = """
 import json
@@ -166,9 +166,7 @@ print("ROWS_JSON:" + json.dumps(rows, default=float))
 
 def run(quick: bool = False) -> list:
     here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ,
-               XLA_FLAGS="--xla_force_host_platform_device_count=8",
-               PYTHONPATH=os.path.join(here, "src"))
+    env = cpu_child_env(8)
     code = _CHILD.format(quick=repr(bool(quick)))
     r = subprocess.run([sys.executable, "-c", code], env=env, cwd=here,
                        capture_output=True, text=True, timeout=1800)
@@ -177,7 +175,7 @@ def run(quick: bool = False) -> list:
     if not payload:
         raise RuntimeError(
             f"apps bench subprocess failed:\n{r.stdout}\n{r.stderr}")
-    rows = json.loads(payload[0][len("ROWS_JSON:"):])
+    rows = mark_cpu(json.loads(payload[0][len("ROWS_JSON:"):]))
     emit(rows, "bench_apps")
     return rows
 

@@ -53,7 +53,7 @@ from repro.core.graph import add_edges
 from repro.core.distributed import run_sharded_hostloop
 from repro.launch.mesh import make_partition_mesh
 
-from .common import emit, get_graph
+from .common import cpu_child_env, emit, get_graph, mark_cpu
 
 EXCHANGE_MATRIX_CODE = """
 import time
@@ -79,9 +79,7 @@ for mode in ("allgather", "halo", "delta"):
 def _exchange_matrix_rows(quick: bool) -> list:
     """allgather/halo/delta wire bytes + wall-clock on an 8-device mesh."""
     here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ,
-               XLA_FLAGS="--xla_force_host_platform_device_count=8",
-               PYTHONPATH=os.path.join(here, "src"))
+    env = cpu_child_env(8)
     code = EXCHANGE_MATRIX_CODE.format(n_per=250 if quick else 500,
                                        max_iters=60 if quick else 120)
     try:
@@ -117,7 +115,7 @@ def _exchange_matrix_rows(quick: bool) -> list:
     if not rows:
         rows.append({"name": "engine/exchange_matrix", "us_per_call": 0.0,
                      "derived": "FAILED: " + (err or "no MODE lines")[-200:]})
-    return rows
+    return mark_cpu(rows)
 
 
 OVERLAP_MATRIX_CODE = """
@@ -156,9 +154,7 @@ def _overlap_matrix_rows(quick: bool) -> list:
     """Overlap-on vs overlap-off wall-clock on an 8-device mesh (halo
     plan; identical trajectories, asserted in the subprocess)."""
     here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ,
-               XLA_FLAGS="--xla_force_host_platform_device_count=8",
-               PYTHONPATH=os.path.join(here, "src"))
+    env = cpu_child_env(8)
     code = OVERLAP_MATRIX_CODE.format(n_per=250 if quick else 500,
                                       max_iters=60 if quick else 120)
     try:
@@ -195,7 +191,7 @@ def _overlap_matrix_rows(quick: bool) -> list:
         rows.append({"name": "engine/overlap_matrix", "us_per_call": 0.0,
                      "derived": "FAILED: "
                                 + (err or "no OVERLAP lines")[-200:]})
-    return rows
+    return mark_cpu(rows)
 
 
 FUSED_MATRIX_CODE = """
@@ -231,9 +227,7 @@ def _fused_matrix_rows(quick: bool) -> list:
     wall-clock here tracks dispatch count, not the TPU win the roofline
     mode models."""
     here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ,
-               XLA_FLAGS="--xla_force_host_platform_device_count=8",
-               PYTHONPATH=os.path.join(here, "src"))
+    env = cpu_child_env(8)
     code = FUSED_MATRIX_CODE.format(n_per=100 if quick else 200,
                                     max_iters=20 if quick else 40)
     try:
@@ -265,7 +259,7 @@ def _fused_matrix_rows(quick: bool) -> list:
         rows.append({"name": "engine/fused_matrix", "us_per_call": 0.0,
                      "derived": "FAILED: "
                                 + (err or "no FUSED lines")[-200:]})
-    return rows
+    return mark_cpu(rows)
 
 
 def _time_engine(graph, cfg, eng, chunk_size=None):
@@ -421,7 +415,7 @@ def run(quick: bool = False) -> list:
         "name": "engine/sharded_pallas",
         "us_per_call": t_pal / max(1, res_p.iterations) * 1e6,
         "derived": f"iters={res_p.iterations};total_s={t_pal:.3f};"
-                   f"interpret={jax.default_backend() != 'tpu'};"
+                   f"interpret={jax.default_backend() == 'cpu'};"
                    f"xla_total_s={t_xla:.3f};parity={parity_p}",
         "iterations": res_p.iterations, "total_s": t_pal,
     })

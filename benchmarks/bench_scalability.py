@@ -14,7 +14,6 @@ per-iteration cost, which is exactly what the sharded engine changes.
 """
 from __future__ import annotations
 
-import os
 import subprocess
 import sys
 import time
@@ -25,7 +24,7 @@ import numpy as np
 from repro.core import SpinnerConfig, generators
 from repro.core.spinner import compute_loads, init_labels, make_step
 
-from .common import emit
+from .common import cpu_child_env, emit
 
 
 def _iter_time(g, k: int, repeats: int = 3) -> float:
@@ -71,7 +70,6 @@ def run(quick: bool = False) -> list:
     # run) in a subprocess with forced host device counts.  halt_window >
     # max_iters disables halting so every device count runs the same fixed
     # iteration count and the per-iteration cost is directly comparable.
-    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     iters = 10 if quick else 20
     for ndev in (1, 2, 4) if quick else (1, 2, 4, 8):
         code = (
@@ -89,9 +87,7 @@ def run(quick: bool = False) -> list:
             "res = partition(g, cfg, **kw);"
             "print('RUN_S', time.time() - t0, res.iterations)"
         )
-        env = dict(os.environ,
-                   XLA_FLAGS=f"--xla_force_host_platform_device_count={ndev}",
-                   PYTHONPATH=os.path.join(here, "src"))
+        env = cpu_child_env(ndev)
         r = subprocess.run([sys.executable, "-c", code], env=env,
                            capture_output=True, text=True, timeout=600)
         line = [ln for ln in r.stdout.splitlines() if "RUN_S" in ln]
@@ -103,9 +99,9 @@ def run(quick: bool = False) -> list:
         rows.append({
             "name": f"scalability/workers{ndev}",
             "us_per_call": dt * 1e6,
-            "derived": f"devices={ndev};iters={ran};"
+            "derived": f"platform=cpu;devices={ndev};iters={ran};"
                        f"run_s={total_s:.3f};engine=sharded",
-            "workers": ndev, "seconds": dt,
+            "workers": ndev, "seconds": dt, "platform": "cpu",
         })
     emit(rows, "bench_scalability")
     return rows

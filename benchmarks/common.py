@@ -44,5 +44,26 @@ def timed(fn: Callable, *args, repeats: int = 1, **kw):
     return out, dt
 
 
+def cpu_child_env(ndev: int) -> dict:
+    """Environment for a benchmark child on ``ndev`` forced host devices.
+
+    Such children measure CPU semantics (multi-device layouts on one
+    host), so they pin ``JAX_PLATFORMS=cpu``: a child never claims an
+    accelerator that its parent already holds.
+    """
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    return dict(os.environ, JAX_PLATFORMS="cpu",
+                XLA_FLAGS=f"--xla_force_host_platform_device_count={ndev}",
+                PYTHONPATH=os.path.join(here, "src"))
+
+
+def mark_cpu(rows: list) -> list:
+    """Label rows measured in a ``cpu_child_env`` child as CPU rows."""
+    for r in rows:
+        r["platform"] = "cpu"
+        r["derived"] = "platform=cpu;" + r.get("derived", "")
+    return rows
+
+
 def hash_labels(v: int, k: int) -> np.ndarray:
     return (np.arange(v) * np.int64(2654435761) % k).astype(np.int32)

@@ -1,8 +1,10 @@
 """Roofline analysis from the dry-run artifacts (deliverable g).
 
-Hardware model (TPU v5e): 197 TFLOP/s bf16, 819 GB/s HBM, ICI ~50 GB/s
-per link; we credit 2 links per chip for a 2D-torus axis -> 100 GB/s/chip
-aggregate collective bandwidth.  All inputs are PER-DEVICE quantities from
+Hardware model: the TPU v5e row of ``repro.kernels.peaks`` (197 TFLOP/s
+bf16, 819 GB/s HBM, 50 GB/s per ICI link; see there for the source); we
+credit 2 links per chip for a 2D-torus axis -> 100 GB/s/chip aggregate
+collective bandwidth.  Every number here is a model of that chip, not a
+measurement.  All inputs are PER-DEVICE quantities from
 the trip-count-weighted HLO analysis (see repro/launch/hlo_analysis.py):
 
   compute_term    = dot_flops / 197e12            (s)
@@ -27,9 +29,12 @@ import json
 import os
 import sys
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-ICI_BW = 100e9          # 2 x 50 GB/s links per torus axis
+from repro.kernels.peaks import device_peaks
+
+_V5E = device_peaks("TPU v5 lite")     # the modelled chip
+PEAK_FLOPS = _V5E.flops
+HBM_BW = _V5E.hbm_bytes_s
+ICI_BW = 2 * _V5E.ici_link_bytes_s     # 2 links per torus axis
 
 ARTIFACTS = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                          "artifacts")

@@ -19,6 +19,8 @@ def main() -> None:
                          "roofline")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from . import (bench_apps, bench_convergence, bench_dynamic,
                    bench_elastic, bench_engine, bench_kernel,
                    bench_placement, bench_quality, bench_scalability,

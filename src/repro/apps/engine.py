@@ -2,7 +2,7 @@
 
 The consumer side of Spinner: given any vertex->device placement
 (``apps.layout``), run a vertex program to convergence as a single
-``jax.jit(shard_map(lax.while_loop))`` dispatch over the device mesh --
+``jax.jit(jax.shard_map(lax.while_loop))`` dispatch over the device mesh --
 the exact architecture of the LPA partitioner engine, re-instantiated
 for application state:
 
@@ -38,7 +38,6 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec
 
 from repro.core import comm
@@ -82,8 +81,8 @@ def _xla_app_args(sg, plan) -> tuple:
     e = sg.e_interior
     d_int, d_fro = kernel_ops._split_dst_views(sg, plan.dst_index)
     wm = (np.asarray(sg.weight) > 0).astype(np.float32)
-    return tuple(map(jnp.asarray, (sg.src_local[:, :e], d_int, wm[:, :e],
-                                   sg.src_local[:, e:], d_fro, wm[:, e:])))
+    return (sg.src_local[:, :e], d_int, wm[:, :e],
+            sg.src_local[:, e:], d_fro, wm[:, e:])
 
 
 def _pallas_app_args(sg, plan, tile_v: int, tile_e: int) -> tuple:
@@ -106,9 +105,8 @@ def _pallas_app_args(sg, plan, tile_v: int, tile_e: int) -> tuple:
                                    tile_e=tile_e, ext_perm=ext)
     wm_i = (st_i.weight > 0).astype(np.float32)
     wm_f = (st_f.weight > 0).astype(np.float32)
-    return tuple(map(jnp.asarray, (st_i.src_local, st_i.dst, wm_i,
-                                   st_f.src_local, st_f.dst, wm_f,
-                                   st_f.perm, st_f.inv_perm)))
+    return (st_i.src_local, st_i.dst, wm_i, st_f.src_local, st_f.dst, wm_f,
+            st_f.perm, st_f.inv_perm)
 
 
 def _make_combine(spec: AppSpec, backend: str, v_local: int,
@@ -248,9 +246,9 @@ def _app_program(spec: AppSpec, mesh: Mesh, axis: str, plan_sig: tuple,
         rep = PartitionSpec()
         arg_specs = (rep, PartitionSpec(axis), PartitionSpec(axis)) \
             + (PartitionSpec(axis),) * n_score + plan_specs
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             run_local, mesh=mesh, in_specs=(spec_s,) + arg_specs,
-            out_specs=spec_s, check_rep=False))
+            out_specs=spec_s, check_vma=False))
 
     return _engine._program(key, build)
 
@@ -336,8 +334,9 @@ def run_app(graph: Graph, labels: np.ndarray, workload: str, *,
     dst_layout = "halo" if plan_obj.dst_index is not layout.sg.dst \
         else "global"
     score_args = _engine._graph_cached(
-        _APP_ARG_CACHE, layout.sg, ("app", combine_sig, dst_layout),
-        args_of)
+        _APP_ARG_CACHE, layout.sg,
+        ("app", combine_sig, dst_layout, mesh, axis),
+        lambda: _engine.shard_rows(args_of(), mesh, axis))
     prog = _app_program(spec, mesh, axis, plan_obj.signature(),
                         combine_sig, overlap, n_steps, damping,
                         len(score_args))

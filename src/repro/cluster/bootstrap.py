@@ -24,15 +24,17 @@ unreliable cloud capacity.  This module stands the capacity up:
   local coordinator + workers for tests and CI (each process pinned to
   its own forced-host-device count via ``XLA_FLAGS``).
 
-Backend note (determined empirically on jax 0.4.37 / CPU): after
-``jax.distributed.initialize`` the global device view spans processes
-and the coordination service (KV store, barriers) works fully, but
-cross-process XLA *computations* raise ``INVALID_ARGUMENT:
-Multiprocess computations aren't implemented on the CPU backend``.  So
-:meth:`ClusterHandle.global_mesh` is constructible everywhere (and
-executable on TPU/GPU backends), while the CPU cluster runtime
-(``repro.cluster.worker``) computes on each process's local mesh and
-exchanges labels/aggregates through the coordination service.
+Backend note (jax 0.9.0, CPU): after ``jax.distributed.initialize`` the
+global device view spans processes, the coordination service (KV
+store, barriers) works, and a jitted reduction over a two-process
+global mesh runs through the CPU backend's gloo collectives (the
+default ``jax_cpu_collectives_implementation``).  The CPU cluster
+runtime (``repro.cluster.worker``) still computes on each process's
+local mesh and exchanges labels/aggregates through the coordination
+service; :meth:`ClusterHandle.global_mesh` is the mesh a worker would
+run the engine's own ``shard_map`` program over instead.  Workers are
+CPU processes: :func:`worker_env` pins ``JAX_PLATFORMS=cpu`` so a
+spawned worker never competes with its parent for an accelerator.
 """
 from __future__ import annotations
 
@@ -337,9 +339,10 @@ def load_local_shard(directory: str, host: int, pad: bool = False):
 
 def worker_env(*, devices_per_process: int = 1,
                extra: Optional[Dict[str, str]] = None) -> Dict[str, str]:
-    """Environment for a spawned worker: forced host devices + src on
-    the path; ``extra`` entries win."""
+    """Environment for a spawned worker: the CPU platform with forced
+    host devices + src on the path; ``extra`` entries win."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count="
                         f"{devices_per_process}")
     env["PYTHONPATH"] = REPO_SRC + (

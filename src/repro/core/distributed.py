@@ -15,8 +15,8 @@ floor for adaptive repartitioning to pay off.  The sharded engine in
     ids, tail padded with degree-0 vertices), edges living on their source
     vertex's owner (zero-weight rows pad the shards square);
   * ``shard_layout`` / ``device_upload`` -- the cached layout per
-    (graph, ndev) and one cached device upload per (layout, array), so
-    mesh sweeps over one graph share a single copy of each;
+    (graph, ndev) and one cached upload per (layout, array, mesh), each
+    row on its own device;
   * ``make_sharded_step`` -- ONE iteration as a jitted ``shard_map``
     dispatch (the engine's step_fn under a per-call ``shard_map``), kept
     for the dispatch-overhead benchmark;
@@ -223,18 +223,18 @@ def shard_layout(graph: Graph, ndev: int, pad: bool = False) -> ShardedGraph:
                                 lambda: shard_graph(graph, ndev, pad=pad))
 
 
-def device_upload(sg: ShardedGraph, field: str) -> jax.Array:
-    """One uploaded shard array (``src_local``/``dst``/``weight``/``deg_w``),
-    cached per (layout, field).
+def device_upload(sg: ShardedGraph, field: str, mesh, axis: str
+                  ) -> jax.Array:
+    """One layout array (e.g. ``deg_w``) with each row on its device of
+    ``mesh``, cached per (layout, field, mesh, axis).
 
-    Keyed on the ShardedGraph identity (itself cached per (graph, ndev))
-    and lazy per array, so runner variants -- different cfg / exchange
-    plan / score backend sweeping one graph on one mesh size -- share a
-    single O(E) device copy of each array they actually use (the Pallas
-    backend, for instance, only ever touches ``deg_w`` here).
+    Keyed on the ShardedGraph identity (itself cached per (graph, ndev)),
+    so runner variants -- different cfg / exchange plan / score backend
+    sweeping one graph on one mesh -- share a single device copy.
     """
-    return engine._graph_cached(_UPLOAD_CACHE, sg, (field,),
-                                lambda: jnp.asarray(getattr(sg, field)))
+    return engine._graph_cached(
+        _UPLOAD_CACHE, sg, (field, mesh, axis),
+        lambda: engine.shard_rows((getattr(sg, field),), mesh, axis)[0])
 
 
 def comm_stats(sg: ShardedGraph, cfg: SpinnerConfig,

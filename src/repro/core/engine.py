@@ -60,8 +60,7 @@ from typing import (Callable, List, NamedTuple, Optional, Sequence, Tuple,
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh, PartitionSpec
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from .graph import Graph, pad_graph, shape_bucket
 
@@ -96,6 +95,18 @@ def _graph_cached(cache: dict, graph, suffix: tuple,
     value = build()
     cache[key] = (weakref.ref(graph, lambda _: cache.pop(key, None)), value)
     return value
+
+
+def shard_rows(arrays, mesh: Mesh, axis: str) -> tuple:
+    """Commit (ndev, ...) host layout arrays row-per-device to ``mesh``.
+
+    A whole-array upload (``jnp.asarray``) lands on the default device:
+    every dispatch of a sharded program then copies the shards out again,
+    and the default device holds every shard for the life of the cache
+    entry.
+    """
+    sharding = NamedSharding(mesh, PartitionSpec(axis))
+    return tuple(jax.device_put(a, sharding) for a in arrays)
 
 
 @dataclasses.dataclass
@@ -1512,9 +1523,9 @@ def _sharded_program(cfg, opts: EngineOptions, mesh: Mesh, axis: str,
         rep = PartitionSpec()
         arg_specs = (rep, rep, PartitionSpec(axis)) \
             + (PartitionSpec(axis),) * n_score + tuple(plan.arg_specs(axis))
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             run_local, mesh=mesh, in_specs=(spec,) + arg_specs,
-            out_specs=spec, check_rep=False))
+            out_specs=spec, check_vma=False))
 
     if score_fn is not None:
         return Program(run=build())
@@ -1557,37 +1568,38 @@ def _sharded_parts(graph: Graph, cfg, opts: EngineOptions, mesh: Mesh,
     sg = shard_layout(padded, ndev, pad=pad)
     plan = comm.make_exchange_plan(opts.resolved_label_exchange(ndev), sg,
                                    delta_cap=opts.delta_cap, pad=pad)
+    # score args are cached per layout: the build retiles/uploads O(E)
+    # arrays (for pallas, a host retile per shard) and depends only on the
+    # layout, the backend, the plan's dst layout and the schedule -- so a
+    # cfg sweep (eps/seed/max_iters/...) over one graph shares one build,
+    # and so do the allgather/delta plans (both index with sg.dst)
+    dst_layout = "halo" if plan.dst_index is not sg.dst else "global"
     if score_fn is None:
         backend = opts.backend()
-        # cached per layout: the build retiles/uploads O(E) arrays (for
-        # pallas, a host retile per shard) and depends only on the layout,
-        # the backend, the plan's dst layout and the schedule -- so a cfg
-        # sweep (eps/seed/max_iters/...) over one graph shares one build,
-        # and so do the allgather/delta plans (both index with sg.dst)
-        dst_layout = "halo" if plan.dst_index is not sg.dst else "global"
         if fused:
             args_of = (backend.sharded_fused_graph_args_split if overlap
                        else backend.sharded_fused_graph_args)
         else:
             args_of = (backend.sharded_graph_args_split if overlap
                        else backend.sharded_graph_args)
-        score_args = _graph_cached(
-            _SCORE_ARG_CACHE, sg,
-            ("sharded", backend.signature(), dst_layout, pad, overlap,
-             fused),
-            lambda: tuple(args_of(sg, cfg.k, plan.dst_index, pad=pad)))
     else:
         # custom closures get the XLA backend's edge layout (same arrays,
         # same normalization), just a different scores fn
         from repro.kernels import ops as kernel_ops
-        score_args = kernel_ops.get_score_backend("xla").sharded_graph_args(
-            sg, cfg.k, plan.dst_index)
+        backend = kernel_ops.get_score_backend("xla")
+        args_of = backend.sharded_graph_args
+    score_args = _graph_cached(
+        _SCORE_ARG_CACHE, sg,
+        ("sharded", backend.signature(), dst_layout, pad, overlap, fused,
+         mesh, axis),
+        lambda: shard_rows(args_of(sg, cfg.k, plan.dst_index, pad=pad),
+                           mesh, axis))
     prog = _sharded_program(cfg, opts, mesh, axis, plan.signature(),
                             len(score_args), score_fn,
                             single_step=single_step, overlap=overlap,
                             fused=fused)
     args = (jnp.float32(cfg.capacity(graph)), jnp.int32(num_real),
-            device_upload(sg, "deg_w")) + tuple(score_args) \
+            device_upload(sg, "deg_w", mesh, axis)) + tuple(score_args) \
         + tuple(plan.device_args())
     return sg, plan, prog, args
 
@@ -1749,9 +1761,9 @@ def _sharded_frontier_program(cfg, opts: EngineOptions, mesh: Mesh,
         rep = PartitionSpec()
         arg_specs = (PartitionSpec(axis), rep, rep, PartitionSpec(axis)) \
             + (PartitionSpec(axis),) * n_score + plan_specs
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             run_local, mesh=mesh, in_specs=(spec,) + arg_specs,
-            out_specs=(spec, rep), check_rep=False))
+            out_specs=(spec, rep), check_vma=False))
 
     return _program(key, build)
 
@@ -1787,13 +1799,15 @@ def _sharded_frontier_parts(graph: Graph, cfg, opts: EngineOptions,
                else backend.sharded_graph_args)
     score_args = _graph_cached(
         _SCORE_ARG_CACHE, sg,
-        ("sharded", backend.signature(), dst_layout, pad, False, fused),
-        lambda: tuple(args_of(sg, cfg.k, plan.dst_index, pad=pad)))
+        ("sharded", backend.signature(), dst_layout, pad, False, fused,
+         mesh, axis),
+        lambda: shard_rows(args_of(sg, cfg.k, plan.dst_index, pad=pad),
+                           mesh, axis))
     prog = _sharded_frontier_program(cfg, opts, mesh, axis,
                                      plan.signature(), len(score_args),
                                      fused=fused)
     args = (jnp.float32(cfg.capacity(graph)), jnp.int32(num_real),
-            device_upload(sg, "deg_w")) + tuple(score_args) \
+            device_upload(sg, "deg_w", mesh, axis)) + tuple(score_args) \
         + tuple(plan.device_args())
     return sg, plan, prog, args
 

@@ -73,15 +73,15 @@ def pagerank(graph: Graph, labels: np.ndarray, k: int, iters: int = 20,
     V = graph.num_vertices
     out_deg = np.bincount(graph.src, minlength=V).astype(np.float64)
     pr = np.full(V, 1.0 / V)
-    stats = []
-    active = np.ones(V, bool)
+    # every vertex sends every superstep, so each superstep's stats match
+    step_stats = _stats(graph, labels, k, np.ones(V, bool))
     for _ in range(iters):
         contrib = np.zeros(V)
         share = pr / np.maximum(out_deg, 1.0)
         np.add.at(contrib, graph.dst, share[graph.src])
         pr = (1 - damping) / V + damping * contrib
-        stats.append(_stats(graph, labels, k, active))
-    return PregelResult(values=pr, supersteps=iters, stats=stats)
+    return PregelResult(values=pr, supersteps=iters,
+                        stats=[step_stats] * iters)
 
 
 def sssp(graph: Graph, source: int, labels: np.ndarray, k: int,

@@ -31,14 +31,13 @@ from typing import Tuple
 
 import numpy as np
 
+from .peaks import device_peaks
+
 # (tile_v, tile_e) sweep; tile_v multiples of 8 (f32 sublane), tile_e is
 # the chunk edge count. 128 lanes keeps every operand MXU/VPU aligned.
 CANDIDATES = ((128, 128), (128, 256), (128, 512),
               (256, 128), (256, 256))
 
-# single source of truth with benchmarks/roofline.py (TPU v5e)
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
 GRID_STEP_OVERHEAD_S = 5e-7   # per-step dispatch/pipeline bubble (model)
 
 _CHOICE_CACHE: dict = {}
@@ -78,7 +77,8 @@ def _shard_cost(deg: np.ndarray, tile_v: int, tile_e: int,
     padded_v = T * tile_v
     flops = 2.0 * e_pad * (tile_v + k_pad)      # two one-hot matmuls
     hbm = e_pad * 12.0 + padded_v * k_pad * 4.0  # edge stream + noise
-    return (flops / PEAK_FLOPS + hbm / HBM_BW
+    peaks = device_peaks()
+    return (flops / peaks.flops + hbm / peaks.hbm_bytes_s
             + T * C * GRID_STEP_OVERHEAD_S)
 
 

@@ -1,8 +1,9 @@
 """Jit'd public wrappers for the Pallas kernels + the score-backend registry.
 
-On a TPU backend the kernel runs compiled; everywhere else it runs in
+On a TPU backend the kernel runs compiled.  On the CPU backend it runs in
 ``interpret=True`` mode (the kernel body executed op-by-op on the host),
-which is how correctness is validated in this repository.
+which is how the tests validate it.  Any other backend is an error: a run
+that misses the chip must not pass for one that used it.
 
 The score-backend protocol at the bottom is how the device-resident engine
 (``repro.core.engine``) picks its ComputeScores implementation: a backend is
@@ -30,7 +31,13 @@ from .spinner_scores import (fused_update_from_tiles, scores_from_tiles,
 
 
 def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Compiled on TPU, interpreted on CPU; no other backend runs them."""
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"the Pallas kernels run compiled on TPU or interpreted on CPU; "
+            f"the default JAX backend is {backend!r}")
+    return backend == "cpu"
 
 
 def round_up(x: int, m: int) -> int:
@@ -87,9 +94,10 @@ class ScoreBackend(Protocol):
         dst_index, pad)`` build the per-graph device arrays the closure
         consumes.  ``pad=True`` buckets derived shapes (the Pallas chunk
         count) so a session rebinding a grown graph keeps the compile
-        shape.  For the sharded form the arrays carry a leading ndev
-        dimension and are threaded through ``shard_map`` with
-        ``PartitionSpec(axis)``; ``dst_index`` is the exchange plan's
+        shape.  For the sharded form the arrays are host arrays with a
+        leading ndev dimension; the engine commits row p to device p
+        (``engine.shard_rows``) and threads them through ``shard_map``
+        with ``PartitionSpec(axis)``; ``dst_index`` is the exchange plan's
         per-edge index (global vertex ids for all-gather/delta,
         halo-remapped slots for halo);
       * ``make_sharded_scores_split(k, v_local)`` /
@@ -211,14 +219,9 @@ class XlaScatterBackend:
 
     def sharded_graph_args(self, sg, k: int, dst_index: np.ndarray,
                            pad: bool = False) -> tuple:
-        from repro.core.distributed import device_upload   # lazy: no cycle
-        # the allgather/delta plans index with the global dst ids verbatim
-        # (dst_index IS sg.dst), so reuse the cached upload; halo's
-        # remapped slots are a genuinely different array
-        dst = (device_upload(sg, "dst") if dst_index is sg.dst
-               else jnp.asarray(np.asarray(dst_index, np.int32)))
-        return (device_upload(sg, "src_local"), dst,
-                device_upload(sg, "weight"))
+        # allgather/delta index with the global dst ids (dst_index IS
+        # sg.dst); halo's remapped slots are a different array
+        return (sg.src_local, np.asarray(dst_index, np.int32), sg.weight)
 
     def make_sharded_scores_split(self, k: int, v_local: int) -> tuple:
         """Two-phase scatter-add over the [interior | frontier] segments
@@ -239,10 +242,8 @@ class XlaScatterBackend:
                                  pad: bool = False) -> tuple:
         e = sg.e_interior
         d_int, d_fro = _split_dst_views(sg, dst_index)
-        return (jnp.asarray(sg.src_local[:, :e]), jnp.asarray(d_int),
-                jnp.asarray(sg.weight[:, :e]),
-                jnp.asarray(sg.src_local[:, e:]), jnp.asarray(d_fro),
-                jnp.asarray(sg.weight[:, e:]))
+        return (sg.src_local[:, :e], d_int, sg.weight[:, :e],
+                sg.src_local[:, e:], d_fro, sg.weight[:, e:])
 
     # ---- fused vertex update: scatter scores + the reference halves ----
     # XLA has no VMEM residency to exploit, so the "fused" form is simply
@@ -350,7 +351,7 @@ class PallasTiledBackend:
     name: str = "pallas"
     tile_v: int = 128
     tile_e: int = 128
-    interpret: Optional[bool] = None   # None -> compiled on TPU else interpret
+    interpret: Optional[bool] = None   # None -> compiled on TPU, interpret on CPU
 
     def _interpret(self) -> bool:
         return (self.interpret if self.interpret is not None
@@ -388,8 +389,7 @@ class PallasTiledBackend:
         st = build_sharded_tiled_csr(sg, dst_index, tile_v=self.tile_v,
                                      tile_e=self.tile_e,
                                      pad_chunks=4 if pad else 1)
-        return tuple(map(jnp.asarray, (st.src_local, st.dst, st.weight,
-                                       st.perm)))
+        return st.src_local, st.dst, st.weight, st.perm
 
     def make_sharded_scores_split(self, k: int, v_local: int) -> tuple:
         """Two kernel launches over independent segment tilings: the
@@ -424,10 +424,8 @@ class PallasTiledBackend:
         st_f = build_sharded_tiled_csr(seg_f, d_fro, tile_v=self.tile_v,
                                        tile_e=self.tile_e,
                                        pad_chunks=4 if pad else 1)
-        return tuple(map(jnp.asarray, (st_i.src_local, st_i.dst,
-                                       st_i.weight, st_i.perm,
-                                       st_f.src_local, st_f.dst,
-                                       st_f.weight, st_f.perm)))
+        return (st_i.src_local, st_i.dst, st_i.weight, st_i.perm,
+                st_f.src_local, st_f.dst, st_f.weight, st_f.perm)
 
     # ---- fused vertex update: the megakernel (scores never hit HBM) ----
     # The (tile_v, k_pad) block stays in VMEM from edge reduction through
@@ -486,8 +484,8 @@ class PallasTiledBackend:
         st = build_sharded_tiled_csr(sg, dst_index, tile_v=self.tile_v,
                                      tile_e=self.tile_e,
                                      pad_chunks=4 if pad else 1)
-        return tuple(map(jnp.asarray, (st.src_local, st.dst, st.weight,
-                                       st.perm, st.inv_perm, st.deg_t)))
+        return (st.src_local, st.dst, st.weight, st.perm, st.inv_perm,
+                st.deg_t)
 
     def make_sharded_fused_update_split(self, k: int, v_local: int, *,
                                         degree_weighted: bool,
@@ -547,11 +545,9 @@ class PallasTiledBackend:
                                        pad_chunks=4 if pad else 1,
                                        ext_perm=ext)
         # shared layout -> one perm/inv_perm/deg_t triple serves both
-        return tuple(map(jnp.asarray, (st_i.src_local, st_i.dst,
-                                       st_i.weight,
-                                       st_f.src_local, st_f.dst,
-                                       st_f.weight, st_f.perm,
-                                       st_f.inv_perm, st_f.deg_t)))
+        return (st_i.src_local, st_i.dst, st_i.weight,
+                st_f.src_local, st_f.dst, st_f.weight,
+                st_f.perm, st_f.inv_perm, st_f.deg_t)
 
     def build(self, graph: Graph, k: int):
         raise _legacy_build_error("build")

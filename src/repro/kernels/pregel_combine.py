@@ -14,8 +14,9 @@ accumulator is revisited across the chunk grid dimension.
 Two combine monoids cover the workload suite (``repro.apps``):
 
   * ``sum``  -- PageRank: a one-hot matmul per chunk, exactly the
-    ``spinner_scores`` reduction with k = 1.  f32, tolerance-exact
-    vs. the XLA scatter-add (different association order).
+    ``spinner_scores`` reduction with k = 1, at full f32 precision (the
+    messages are not small integers).  Tolerance-exact vs. the XLA
+    scatter-add (different association order).
   * ``min``  -- WCC / BFS / SSSP: a masked minimum per chunk.  int32,
     BIT-exact vs. the XLA ``.at[].min`` path (min is order-free).
 
@@ -44,6 +45,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .spinner_scores import COMPILER_PARAMS, edge_spec, edge_view, row_spec
+
 INF_I32 = 2 ** 30        # "unreached" sentinel for min-combine workloads
 
 
@@ -53,8 +56,11 @@ def _accumulate(acc_ref, sl, msg, wm, *, tile_v: int, combine: str):
     hit = sl[:, None] == rows                       # (TILE_E, TILE_V)
     if combine == "sum":
         onehot_v = hit.astype(jnp.float32)
+        # HIGHEST: the MXU's default f32 precision rounds the messages to
+        # bf16 (~3e-3 relative error in PageRank on the chip)
         part = jax.lax.dot_general(                 # (TILE_V, 1) on the MXU
             onehot_v, (msg * wm)[:, None], (((0,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32)
         acc_ref[...] += part[:, 0][None, :]
     else:                                           # min
@@ -85,8 +91,8 @@ def _reduce_kernel(*refs, tile_v: int, nc: int, combine: str,
         else:
             acc_ref[...] = init_ref[...]
 
-    _accumulate(acc_ref, src_ref[0, 0, :], msg_ref[0, 0, :],
-                wm_ref[0, 0, :], tile_v=tile_v, combine=combine)
+    _accumulate(acc_ref, src_ref[0, :], msg_ref[0, :], wm_ref[0, :],
+                tile_v=tile_v, combine=combine)
 
     @pl.when(j == nc - 1)
     def _emit():
@@ -111,8 +117,8 @@ def _fused_kernel(*refs, tile_v: int, nc: int, combine: str, update: str,
         else:
             acc_ref[...] = init_ref[...]
 
-    _accumulate(acc_ref, src_ref[0, 0, :], msg_ref[0, 0, :],
-                wm_ref[0, 0, :], tile_v=tile_v, combine=combine)
+    _accumulate(acc_ref, src_ref[0, :], msg_ref[0, :], wm_ref[0, :],
+                tile_v=tile_v, combine=combine)
 
     @pl.when(j == nc - 1)
     def _vertex_update():
@@ -151,25 +157,24 @@ def pregel_reduce_pallas(src_local: jax.Array, msg: jax.Array,
     kernel = functools.partial(_reduce_kernel, tile_v=tile_v, nc=c,
                                combine=combine,
                                has_init=acc_init is not None)
-    edge_spec = pl.BlockSpec((1, 1, tile_e), lambda i, j: (i, j, 0))
-    row_spec = pl.BlockSpec((1, tile_v), lambda i, j: (i, 0))
-    in_specs = [edge_spec, edge_spec, edge_spec]
-    args = [src_local, msg.astype(dtype), wm]
+    e_spec, r_spec = edge_spec(tile_e), row_spec(tile_v)
+    in_specs = [e_spec, e_spec, e_spec]
+    args = [edge_view(src_local), edge_view(msg.astype(dtype)),
+            edge_view(wm)]
     if acc_init is not None:
-        in_specs.append(row_spec)
-        args.append(acc_init.astype(dtype))
-    return pl.pallas_call(
+        in_specs.append(r_spec)
+        args.append(acc_init.astype(dtype)[:, None, :])
+    out = pl.pallas_call(
         kernel,
         grid=(t, c),
         in_specs=in_specs,
-        out_specs=row_spec,
-        out_shape=jax.ShapeDtypeStruct((t, tile_v), dtype),
+        out_specs=r_spec,
+        out_shape=jax.ShapeDtypeStruct((t, 1, tile_v), dtype),
         scratch_shapes=[pltpu.VMEM((1, tile_v), dtype)],
         interpret=interpret,
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("arbitrary", "arbitrary"))
-        ) if not interpret else None,
+        compiler_params=COMPILER_PARAMS,
     )(*args)
+    return out[:, 0]
 
 
 def pregel_combine_pallas(src_local: jax.Array, msg: jax.Array,
@@ -193,29 +198,27 @@ def pregel_combine_pallas(src_local: jax.Array, msg: jax.Array,
                                combine=combine, update=update,
                                damping=float(damping),
                                has_init=acc_init is not None)
-    edge_spec = pl.BlockSpec((1, 1, tile_e), lambda i, j: (i, j, 0))
-    row_spec = pl.BlockSpec((1, tile_v), lambda i, j: (i, 0))
-    in_specs = [edge_spec, edge_spec, edge_spec,
-                row_spec, row_spec, row_spec]
-    args = [src_local, msg.astype(dtype), wm, vals.astype(dtype),
-            valid.astype(jnp.int32), base.astype(jnp.float32)]
+    e_spec, r_spec = edge_spec(tile_e), row_spec(tile_v)
+    in_specs = [e_spec, e_spec, e_spec, r_spec, r_spec, r_spec]
+    args = [edge_view(src_local), edge_view(msg.astype(dtype)),
+            edge_view(wm)] + [
+        a[:, None, :] for a in (vals.astype(dtype), valid.astype(jnp.int32),
+                                base.astype(jnp.float32))]
     if acc_init is not None:
-        in_specs.append(row_spec)
-        args.append(acc_init.astype(dtype))
+        in_specs.append(r_spec)
+        args.append(acc_init.astype(dtype)[:, None, :])
     out, chg = pl.pallas_call(
         kernel,
         grid=(t, c),
         in_specs=in_specs,
-        out_specs=[row_spec, row_spec],
-        out_shape=[jax.ShapeDtypeStruct((t, tile_v), dtype),
-                   jax.ShapeDtypeStruct((t, tile_v), jnp.int32)],
+        out_specs=[r_spec, r_spec],
+        out_shape=[jax.ShapeDtypeStruct((t, 1, tile_v), dtype),
+                   jax.ShapeDtypeStruct((t, 1, tile_v), jnp.int32)],
         scratch_shapes=[pltpu.VMEM((1, tile_v), dtype)],
         interpret=interpret,
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("arbitrary", "arbitrary"))
-        ) if not interpret else None,
+        compiler_params=COMPILER_PARAMS,
     )(*args)
-    return out, chg
+    return out[:, 0], chg[:, 0]
 
 
 # ---------------------------------------------------------------------------

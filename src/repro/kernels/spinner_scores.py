@@ -54,6 +54,33 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+# Both grid axes revisit an output block (the accumulator across chunks, the
+# migration mass across tiles), so neither may be split across cores.
+COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("arbitrary", "arbitrary"))
+
+
+# Mosaic tiles the last two dims of every block by (8, 128) unless a block
+# dim spans the whole array dim.  So each (TILE_E,) edge chunk travels as
+# the (1, TILE_E) row of a (T, C, 1, TILE_E) view, and each (tile_v,)
+# per-vertex vector as the (1, tile_v) row of a (T, 1, tile_v) view; the
+# leading grid dims are squeezed, so kernels see 2-D (1, n) refs.
+
+def edge_view(x: jax.Array) -> jax.Array:
+    """(T, C, TILE_E) -> (T, C, 1, TILE_E) for ``edge_spec``."""
+    t, c, tile_e = x.shape
+    return x.reshape(t, c, 1, tile_e)
+
+
+def edge_spec(tile_e: int) -> pl.BlockSpec:
+    return pl.BlockSpec((pl.squeezed, pl.squeezed, 1, tile_e),
+                        lambda i, j: (i, j, 0, 0))
+
+
+def row_spec(tile_v: int) -> pl.BlockSpec:
+    """Block of a (T, 1, tile_v) per-tile row array."""
+    return pl.BlockSpec((pl.squeezed, 1, tile_v), lambda i, j: (i, 0, 0))
+
 
 def _kernel(src_local_ref, dst_label_ref, w_ref, out_ref, *, tile_v: int,
             k_pad: int):
@@ -63,9 +90,9 @@ def _kernel(src_local_ref, dst_label_ref, w_ref, out_ref, *, tile_v: int,
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    sl = src_local_ref[0, 0, :]                       # (TILE_E,) int32
-    lbl = dst_label_ref[0, 0, :]                      # (TILE_E,) int32
-    w = w_ref[0, 0, :]                                # (TILE_E,) f32
+    sl = src_local_ref[0, :]                          # (TILE_E,) int32
+    lbl = dst_label_ref[0, :]                         # (TILE_E,) int32
+    w = w_ref[0, :]                                   # (TILE_E,) f32
 
     rows = jax.lax.broadcasted_iota(jnp.int32, (sl.shape[0], tile_v), 1)
     onehot_v = (sl[:, None] == rows).astype(jnp.float32)
@@ -114,18 +141,15 @@ def spinner_scores_pallas(src_local: jax.Array, dst_label: jax.Array,
     t, c, tile_e = src_local.shape
     assert dst_label.shape == w.shape == (t, c, tile_e)
     kernel = functools.partial(_kernel, tile_v=tile_v, k_pad=k_pad)
-    edge_spec = pl.BlockSpec((1, 1, tile_e), lambda i, j: (i, j, 0))
     return pl.pallas_call(
         kernel,
         grid=(t, c),
-        in_specs=[edge_spec, edge_spec, edge_spec],
+        in_specs=[edge_spec(tile_e)] * 3,
         out_specs=pl.BlockSpec((tile_v, k_pad), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((t * tile_v, k_pad), jnp.float32),
         interpret=interpret,
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("arbitrary", "arbitrary"))
-        ) if not interpret else None,
-    )(src_local, dst_label, w)
+        compiler_params=COMPILER_PARAMS,
+    )(edge_view(src_local), edge_view(dst_label), edge_view(w))
 
 
 # ---------------------------------------------------------------------------
@@ -175,9 +199,9 @@ def _fused_kernel(*refs, tile_v: int, k_pad: int, k: int, nc: int,
                         else jnp.zeros_like(acc_ref))
 
     def _accumulate():
-        sl = src_ref[0, 0, :]                         # (TILE_E,) int32
-        lbl = lbl_ref[0, 0, :]                        # (TILE_E,) int32
-        w = w_ref[0, 0, :]                            # (TILE_E,) f32
+        sl = src_ref[0, :]                            # (TILE_E,) int32
+        lbl = lbl_ref[0, :]                           # (TILE_E,) int32
+        w = w_ref[0, :]                               # (TILE_E,) f32
         rows = jax.lax.broadcasted_iota(jnp.int32, (sl.shape[0], tile_v), 1)
         onehot_v = (sl[:, None] == rows).astype(jnp.float32)
         ecols = jax.lax.broadcasted_iota(jnp.int32, (lbl.shape[0], k_pad), 1)
@@ -273,36 +297,35 @@ def fused_update_pallas(src_local: jax.Array, dst_label: jax.Array,
         current_bonus=float(current_bonus),
         degree_weighted=degree_weighted, has_init=acc_init is not None,
         has_act=tile_act is not None)
-    edge_spec = pl.BlockSpec((1, 1, tile_e), lambda i, j: (i, j, 0))
-    row_spec = pl.BlockSpec((1, tile_v), lambda i, j: (i, 0))
+    e_spec, r_spec = edge_spec(tile_e), row_spec(tile_v)
     mat_spec = pl.BlockSpec((tile_v, k_pad), lambda i, j: (i, 0))
     k_spec = pl.BlockSpec((1, k_pad), lambda i, j: (0, 0))
-    act_spec = pl.BlockSpec((1, 1), lambda i, j: (i, 0))
-    in_specs = [edge_spec, edge_spec, edge_spec, row_spec, row_spec,
-                row_spec, k_spec, mat_spec]
-    inputs = [src_local, dst_label, w, labels_t, deg_t, valid_t,
+    in_specs = [e_spec, e_spec, e_spec, r_spec, r_spec, r_spec, k_spec,
+                mat_spec]
+    inputs = [edge_view(src_local), edge_view(dst_label), edge_view(w),
+              labels_t[:, None, :], deg_t[:, None, :], valid_t[:, None, :],
               penalty_row, noise_t]
     if acc_init is not None:
         in_specs.append(mat_spec)
         inputs.append(acc_init)
     if tile_act is not None:
-        in_specs.append(act_spec)
-        inputs.append(tile_act)
-    return pl.pallas_call(
+        in_specs.append(pl.BlockSpec((pl.squeezed, 1, 1),
+                                     lambda i, j: (i, 0, 0)))
+        inputs.append(tile_act[:, None, :])
+    best, tb, tc, m = pl.pallas_call(
         kernel,
         grid=(t, c),
         in_specs=in_specs,
-        out_specs=[row_spec, row_spec, row_spec, k_spec],
-        out_shape=[jax.ShapeDtypeStruct((t, tile_v), jnp.int32),
-                   jax.ShapeDtypeStruct((t, tile_v), jnp.float32),
-                   jax.ShapeDtypeStruct((t, tile_v), jnp.float32),
+        out_specs=[r_spec, r_spec, r_spec, k_spec],
+        out_shape=[jax.ShapeDtypeStruct((t, 1, tile_v), jnp.int32),
+                   jax.ShapeDtypeStruct((t, 1, tile_v), jnp.float32),
+                   jax.ShapeDtypeStruct((t, 1, tile_v), jnp.float32),
                    jax.ShapeDtypeStruct((1, k_pad), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((tile_v, k_pad), jnp.float32)],
         interpret=interpret,
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("arbitrary", "arbitrary"))
-        ) if not interpret else None,
+        compiler_params=COMPILER_PARAMS,
     )(*inputs)
+    return best[:, 0], tb[:, 0], tc[:, 0], m
 
 
 def fused_update_from_tiles(labels_lookup: jax.Array, labels: jax.Array,

@@ -19,11 +19,8 @@ MODEL = "__model__"
 
 
 def current_mesh() -> Optional[jax.sharding.Mesh]:
-    # jax.sharding.get_abstract_mesh only exists on newer jax; older
-    # versions track the active mesh solely via thread_resources below.
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    m = get_abstract() if get_abstract is not None else None
-    if m is not None and not m.empty and m.axis_names:
+    m = jax.sharding.get_abstract_mesh()
+    if not m.empty and m.axis_names:
         return m
     try:
         from jax._src import mesh as mesh_lib

@@ -53,6 +53,7 @@ the round regardless of priority.
 from __future__ import annotations
 
 import dataclasses
+import logging
 import os
 import time
 from typing import Callable, Dict, List, NamedTuple, Optional, Sequence
@@ -68,6 +69,8 @@ from repro.core.session import PartitionSession
 from repro.core.spinner import SpinnerConfig
 
 from .requests import KINDS, Tenant, Ticket
+
+_log = logging.getLogger(__name__)
 
 
 class _Work(NamedTuple):
@@ -477,8 +480,9 @@ class PartitionScheduler:
             try:
                 p.run(self)
             except Exception as e:    # prefetch must never fail serving
-                self._policy_errors.append(
-                    f"{getattr(p, 'name', type(p).__name__)}: {e!r}")
+                name = getattr(p, "name", type(p).__name__)
+                _log.exception("scheduler policy %s failed", name)
+                self._policy_errors.append(f"{name}: {e!r}")
 
     def _finish(self, t: Tenant, window: List[Ticket], res) -> int:
         now = self.clock()

@@ -119,8 +119,7 @@ class TestShardingRules:
 
     def test_batch_rule_replicates_batch1(self):
         # AbstractMesh gives real axis extents without needing 256 devices
-        # (jax 0.4.37 signature: a tuple of (axis_name, size) pairs)
-        mesh = jax.sharding.AbstractMesh((("data", 16), ("model", 16)))
+        mesh = jax.sharding.AbstractMesh((16, 16), ("data", "model"))
         import jax.numpy as jnp
         from repro.models.common import spec as mkspec
         b = {"token": mkspec(1, dtype=jnp.int32),
