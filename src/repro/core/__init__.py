@@ -1,6 +1,6 @@
 """Spinner core: the paper's contribution as a composable JAX module."""
 from . import comm, delta, engine, generators, graph, incremental, metrics, \
-    session
+    session, trace
 from .delta import (DeltaTracker, DeviceDelta, apply_delta,
                     check_edge_updates, coalesce_updates)
 from .engine import (EngineOptions, SpinnerState, batch_signature,
@@ -38,5 +38,5 @@ __all__ = [
     "phi", "phi_weighted", "rho", "score_global", "comm_volume",
     "frontier_fraction",
     "partitioning_difference", "summarize", "comm", "delta", "engine",
-    "generators", "graph", "metrics", "incremental", "session",
+    "generators", "graph", "metrics", "incremental", "session", "trace",
 ]

@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import trace
 from .graph import Graph, shape_bucket
 
 # Batch arrays are padded to a bucketed length so every same-bucket batch
@@ -429,7 +430,8 @@ def apply_batch(dd: DeviceDelta, plan: BatchPlan, slotting,
     ``merge_run`` is the engine's ``("delta_merge",)`` program callable.
     Returns the updated ``DeviceDelta`` (fresh jnp arrays, functional
     update) and the batch upload byte count -- O(|delta|), the transfer
-    the session's ``stats()`` counters account.
+    the session's ``stats()`` counters account.  The batch's uploads run
+    in the span ``delta/upload``, the merge's dispatch in ``delta/merge``.
     """
     slots, commit = slotting
     n = plan.num_entries
@@ -442,66 +444,76 @@ def apply_batch(dd: DeviceDelta, plan: BatchPlan, slotting,
         host_arrays.append(a)
         return jnp.asarray(a)
 
-    if dd.mode == "single_xla":
-        (coo_slots,) = slots
-        idx = dev(_bucket_pad([(coo_slots, True)], n,
-                              int(dd.score[0].size))[0])
-        vs, vd, vw = (dev(a) for a in _bucket_pad(
-            [(src32, False), (dst32, False), (dw32, False)], n, 0))
-        set_groups = ((dd.score, idx, (vs, vd, vw)),)
-        didx = dev(_bucket_pad([(plan.src.astype(np.int64), True)], n,
-                               int(dd.deg_w.size))[0])
-        add_groups = ((dd.deg_w, didx, vw),)
-        (new_score,), (new_deg,) = merge_run(set_groups, add_groups)
-        out = dataclasses.replace(dd, score=tuple(new_score),
-                                  deg_w=new_deg)
-    elif dd.mode == "single_pallas":
-        tile_slots, coo_slots = slots
-        sl_local = (dd.perm[plan.src] % dd.tile_v).astype(np.int32)
-        t_idx = dev(_bucket_pad([(tile_slots, True)], n,
-                                int(dd.score[0].size))[0])
-        c_idx = dev(_bucket_pad([(coo_slots, True)], n,
-                                int(dd.coo[0].size))[0])
-        v_sl, v_s, v_d, v_w = (dev(a) for a in _bucket_pad(
-            [(sl_local, False), (src32, False), (dst32, False),
-             (dw32, False)], n, 0))
-        # tiled (src_local, dst, weight) share tile slots; the COO mirror
-        # (frontier expansion index) shares its own tail slots
-        set_groups = (
-            ((dd.score[0], dd.score[1], dd.score[2]), t_idx,
-             (v_sl, v_d, v_w)),
-            (dd.coo, c_idx, (v_s, v_d)),
-        )
-        row_idx = dev(_bucket_pad(
-            [(dd.perm[plan.src].astype(np.int64), True)], n,
-            int(dd.score[5].size))[0])
-        deg_idx = dev(_bucket_pad([(plan.src.astype(np.int64), True)], n,
-                                  int(dd.deg_w.size))[0])
-        add_groups = ((dd.score[5], row_idx, v_w),
-                      (dd.deg_w, deg_idx, v_w))
-        (tiled3, coo2), (new_deg_t, new_deg) = merge_run(set_groups,
-                                                         add_groups)
-        out = dataclasses.replace(
-            dd, score=tuple(tiled3) + dd.score[3:5] + (new_deg_t,),
-            coo=tuple(coo2), deg_w=new_deg)
-    elif dd.mode == "sharded_xla":
-        (flat_slots,) = slots
-        sl_local = (plan.src.astype(np.int64) % dd.v_per_dev
-                    ).astype(np.int32)
-        idx = dev(_bucket_pad([(flat_slots, True)], n,
-                              int(dd.score[0].size))[0])
-        v_sl, v_d, v_w = (dev(a) for a in _bucket_pad(
-            [(sl_local, False), (dst32, False), (dw32, False)], n, 0))
-        set_groups = ((dd.score, idx, (v_sl, v_d, v_w)),)
-        # deg_w is (ndev, v_per_dev) over contiguous ranges: flat id = u
-        didx = dev(_bucket_pad([(plan.src.astype(np.int64), True)], n,
-                               int(dd.deg_w.size))[0])
-        add_groups = ((dd.deg_w, didx, v_w),)
-        (new_score,), (new_deg,) = merge_run(set_groups, add_groups)
-        out = dataclasses.replace(dd, score=tuple(new_score),
-                                  deg_w=new_deg)
-    else:
-        raise ValueError(f"unknown DeviceDelta mode {dd.mode!r}")
+    with trace.span("delta/upload") as at:
+        if dd.mode == "single_xla":
+            (coo_slots,) = slots
+            idx = dev(_bucket_pad([(coo_slots, True)], n,
+                                  int(dd.score[0].size))[0])
+            vs, vd, vw = (dev(a) for a in _bucket_pad(
+                [(src32, False), (dst32, False), (dw32, False)], n, 0))
+            set_groups = ((dd.score, idx, (vs, vd, vw)),)
+            didx = dev(_bucket_pad([(plan.src.astype(np.int64), True)], n,
+                                   int(dd.deg_w.size))[0])
+            add_groups = ((dd.deg_w, didx, vw),)
+
+            def unpack(merged):
+                (new_score,), (new_deg,) = merged
+                return dataclasses.replace(dd, score=tuple(new_score),
+                                           deg_w=new_deg)
+        elif dd.mode == "single_pallas":
+            tile_slots, coo_slots = slots
+            sl_local = (dd.perm[plan.src] % dd.tile_v).astype(np.int32)
+            t_idx = dev(_bucket_pad([(tile_slots, True)], n,
+                                    int(dd.score[0].size))[0])
+            c_idx = dev(_bucket_pad([(coo_slots, True)], n,
+                                    int(dd.coo[0].size))[0])
+            v_sl, v_s, v_d, v_w = (dev(a) for a in _bucket_pad(
+                [(sl_local, False), (src32, False), (dst32, False),
+                 (dw32, False)], n, 0))
+            # tiled (src_local, dst, weight) share tile slots; the COO
+            # mirror (frontier expansion index) shares its own tail slots
+            set_groups = (
+                ((dd.score[0], dd.score[1], dd.score[2]), t_idx,
+                 (v_sl, v_d, v_w)),
+                (dd.coo, c_idx, (v_s, v_d)),
+            )
+            row_idx = dev(_bucket_pad(
+                [(dd.perm[plan.src].astype(np.int64), True)], n,
+                int(dd.score[5].size))[0])
+            deg_idx = dev(_bucket_pad([(plan.src.astype(np.int64), True)],
+                                      n, int(dd.deg_w.size))[0])
+            add_groups = ((dd.score[5], row_idx, v_w),
+                          (dd.deg_w, deg_idx, v_w))
+
+            def unpack(merged):
+                (tiled3, coo2), (new_deg_t, new_deg) = merged
+                return dataclasses.replace(
+                    dd, score=tuple(tiled3) + dd.score[3:5] + (new_deg_t,),
+                    coo=tuple(coo2), deg_w=new_deg)
+        elif dd.mode == "sharded_xla":
+            (flat_slots,) = slots
+            sl_local = (plan.src.astype(np.int64) % dd.v_per_dev
+                        ).astype(np.int32)
+            idx = dev(_bucket_pad([(flat_slots, True)], n,
+                                  int(dd.score[0].size))[0])
+            v_sl, v_d, v_w = (dev(a) for a in _bucket_pad(
+                [(sl_local, False), (dst32, False), (dw32, False)], n, 0))
+            set_groups = ((dd.score, idx, (v_sl, v_d, v_w)),)
+            # deg_w is (ndev, v_per_dev) over contiguous ranges: flat id = u
+            didx = dev(_bucket_pad([(plan.src.astype(np.int64), True)], n,
+                                   int(dd.deg_w.size))[0])
+            add_groups = ((dd.deg_w, didx, v_w),)
+
+            def unpack(merged):
+                (new_score,), (new_deg,) = merged
+                return dataclasses.replace(dd, score=tuple(new_score),
+                                           deg_w=new_deg)
+        else:
+            raise ValueError(f"unknown DeviceDelta mode {dd.mode!r}")
+        nbytes = int(sum(a.nbytes for a in host_arrays))
+        at["bytes"] = nbytes
+    with trace.span("delta/merge"):
+        out = unpack(merge_run(set_groups, add_groups))
     # commit AFTER a successful scatter but BEFORE snapshotting the host
     # slot state into the returned DeviceDelta (commit mutates dd's
     # fill/next_slot fields in place)
@@ -509,7 +521,7 @@ def apply_batch(dd: DeviceDelta, plan: BatchPlan, slotting,
     out = dataclasses.replace(
         out, next_slot=dd.next_slot, fill=dd.fill,
         int_fill=dd.int_fill, fro_fill=dd.fro_fill)
-    return out, int(sum(a.nbytes for a in host_arrays))
+    return out, nbytes
 
 
 def apply_delta(tracker: DeltaTracker, dd: DeviceDelta, src, dst,
@@ -528,10 +540,12 @@ def apply_delta(tracker: DeltaTracker, dd: DeviceDelta, src, dst,
     """
     src = np.asarray(src, np.int64)
     dst = np.asarray(dst, np.int64)
-    plan = tracker.plan(src, dst)
+    with trace.span("delta/plan") as at:
+        plan = tracker.plan(src, dst)
+        slotting = plan_slots(dd, plan) if plan.num_entries else None
+        at["entries"] = plan.num_entries
     nbytes = 0
     if plan.num_entries:
-        slotting = plan_slots(dd, plan)
         if slotting is None:
             return None
         dd, nbytes = apply_batch(dd, plan, slotting, merge_run)

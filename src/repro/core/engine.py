@@ -485,6 +485,10 @@ def make_update_parts(k: int, *, degree_weighted: bool,
     """
 
     def propose(scores, labels, deg_w, loads, noise, valid, C):
+        with jax.named_scope("lpa/propose"):
+            return _propose(scores, labels, deg_w, loads, noise, valid, C)
+
+    def _propose(scores, labels, deg_w, loads, noise, valid, C):
         # ---- ComputeScores (Eq. 8) -------------------------------------
         norm = scores / jnp.maximum(deg_w, 1.0)[:, None]
         penalty = loads / C                                # pi(l) (Eq. 7)
@@ -505,6 +509,12 @@ def make_update_parts(k: int, *, degree_weighted: bool,
 
     def finish(best, tot_best, tot_cur, m_partial, labels, deg_w, loads,
                u, valid, reduce_, C):
+        with jax.named_scope("lpa/migrate"):
+            return _finish(best, tot_best, tot_cur, m_partial, labels,
+                           deg_w, loads, u, valid, reduce_, C)
+
+    def _finish(best, tot_best, tot_cur, m_partial, labels, deg_w, loads,
+                u, valid, reduce_, C):
         want = best != labels
         if valid is not None:
             want = want & valid
@@ -574,13 +584,46 @@ def _halting_update(best_score, stall, score_g, eps, halt_window):
     On the first iteration best_score is -inf, so tol is inf and
     ``best + tol`` is NaN: the comparison is False and the iteration counts
     toward the stall window -- the same (intentional) behaviour as the
-    legacy host loop's float arithmetic.
+    legacy host loop's float arithmetic.  Device scope ``lpa/halt``.
     """
-    tol = eps * jnp.maximum(jnp.float32(1.0), jnp.abs(best_score))
-    improved = score_g > best_score + tol
-    new_best = jnp.maximum(best_score, score_g)
-    new_stall = jnp.where(improved, jnp.int32(0), stall + 1)
-    return new_best, new_stall, new_stall >= halt_window
+    with jax.named_scope("lpa/halt"):
+        tol = eps * jnp.maximum(jnp.float32(1.0), jnp.abs(best_score))
+        improved = score_g > best_score + tol
+        new_best = jnp.maximum(best_score, score_g)
+        new_stall = jnp.where(improved, jnp.int32(0), stall + 1)
+        return new_best, new_stall, new_stall >= halt_window
+
+
+def _noise_draws(key, v: int, k: int, tie: float):
+    k_noise, k_mig = jax.random.split(key)
+    return (jax.random.uniform(k_noise, (v, k), jnp.float32, 0.0, tie),
+            jax.random.uniform(k_mig, (v,), jnp.float32))
+
+
+def _draw_noise(key, v_pad: int, k: int, tie: float):
+    """One iteration's (v_pad, k) tie noise in [0, tie) and (v_pad,)
+    migration draws ``u`` (device scope ``lpa/noise``)."""
+    with jax.named_scope("lpa/noise"):
+        return _noise_draws(key, v_pad, k, tie)
+
+
+def _draw_shard_noise(key, axis: str, noise_mode: str, ndev: int,
+                     v_local: int, k: int, tie: float):
+    """This device's rows of ``_draw_noise`` inside ``shard_map``.
+
+    ``"replicated"`` draws over the full padded set from the replicated
+    key and slices the local rows (bit-identical to the single-device
+    draw on a 1-device mesh); ``"folded"`` folds the axis index into the
+    key and draws only the local ``(v_local, k)`` block."""
+    with jax.named_scope("lpa/noise"):
+        if noise_mode == "folded":
+            return _noise_draws(
+                jax.random.fold_in(key, jax.lax.axis_index(axis)),
+                v_local, k, tie)
+        off = jax.lax.axis_index(axis) * v_local
+        noise, u = _noise_draws(key, ndev * v_local, k, tie)
+        return (jax.lax.dynamic_slice_in_dim(noise, off, v_local, 0),
+                jax.lax.dynamic_slice_in_dim(u, off, v_local, 0))
 
 
 def _bind_iterate(cfg, scores_fn: Callable, fused: bool = False) -> Callable:
@@ -601,10 +644,7 @@ def _bind_iterate(cfg, scores_fn: Callable, fused: bool = False) -> Callable:
 
     def iterate(labels, loads, key, bind: GraphBind):
         v_pad = labels.shape[0]
-        k_noise, k_mig = jax.random.split(key)
-        noise = jax.random.uniform(k_noise, (v_pad, k), jnp.float32,
-                                   0.0, tie)
-        u = jax.random.uniform(k_mig, (v_pad,), jnp.float32)
+        noise, u = _draw_noise(key, v_pad, k, tie)
         valid = jnp.arange(v_pad, dtype=jnp.int32) < bind.num_real
         if fused:
             return scores_fn(labels, labels, bind.deg_w, loads, noise, u,
@@ -824,10 +864,7 @@ def _bind_frontier_step(cfg, scores_fn: Callable, fused: bool) -> Callable:
         state, active, hist = carry
         key, k_it = jax.random.split(state.key)
         v_pad = state.labels.shape[0]
-        k_noise, k_mig = jax.random.split(k_it)
-        noise = jax.random.uniform(k_noise, (v_pad, k), jnp.float32,
-                                   0.0, tie)
-        u = jax.random.uniform(k_mig, (v_pad,), jnp.float32)
+        noise, u = _draw_noise(k_it, v_pad, k, tie)
         valid = (jnp.arange(v_pad, dtype=jnp.int32) < bind.num_real) \
             & active
         if fused:
@@ -845,9 +882,10 @@ def _bind_frontier_step(cfg, scores_fn: Callable, fused: bool) -> Callable:
                 bind.deg_w, state.loads, u, valid, lambda x: x,
                 bind.capacity)
         src, dst = bind.frontier
-        changed = (labels != state.labels).astype(jnp.int32)
-        touched = jnp.zeros((v_pad,), jnp.int32).at[src].max(
-            changed[dst]) > 0
+        with jax.named_scope("lpa/expand"):
+            changed = (labels != state.labels).astype(jnp.int32)
+            touched = jnp.zeros((v_pad,), jnp.int32).at[src].max(
+                changed[dst]) > 0
         hist = hist.at[state.iteration].set(
             jnp.sum(valid.astype(jnp.float32)))
         best_s, stall, _ = _halting_update(
@@ -948,13 +986,14 @@ def _merge_program() -> Program:
     def build():
         @jax.jit
         def run(set_groups, add_groups):
-            merged = tuple(
-                tuple(a.reshape(-1).at[idx].set(v, mode="drop")
-                      .reshape(a.shape) for a, v in zip(arrays, vals))
-                for arrays, idx, vals in set_groups)
-            bumped = tuple(
-                a.reshape(-1).at[idx].add(inc, mode="drop").reshape(a.shape)
-                for a, idx, inc in add_groups)
+            with jax.named_scope("delta/merge"):
+                merged = tuple(
+                    tuple(a.reshape(-1).at[idx].set(v, mode="drop")
+                          .reshape(a.shape) for a, v in zip(arrays, vals))
+                    for arrays, idx, vals in set_groups)
+                bumped = tuple(
+                    a.reshape(-1).at[idx].add(inc, mode="drop")
+                    .reshape(a.shape) for a, idx, inc in add_groups)
             return merged, bumped
 
         return run
@@ -1264,7 +1303,16 @@ def run_chunked(graph: Graph, cfg, labels, loads, key,
                                     record=record, opts=opts)
     if on_program is not None:
         on_program(getattr(run_chunk, "program", None))
-    state = init_state(labels, loads, key)
+    return drive_chunks(run_chunk, init_state(labels, loads, key), cfg,
+                        chunk_size, record, callback)
+
+
+def drive_chunks(run_chunk: Callable, state: SpinnerState, cfg,
+                 chunk_size: int, record: bool,
+                 callback: Optional[Callable[[int, dict], None]] = None,
+                 ) -> Tuple[SpinnerState, List[dict]]:
+    """Dispatch ``run_chunk`` (``make_chunked_runner``) until the run
+    halts or reaches ``max_iters``: ``(state, history)``."""
     history: List[dict] = []
     num_chunks = -(-cfg.max_iters // chunk_size)
     for _ in range(num_chunks):
@@ -1377,7 +1425,6 @@ def make_sharded_step_fn(cfg, axis: str, ndev: int, v_local: int, plan,
     of a different (still deterministic) stream.
     """
     k = cfg.k
-    v_pad = ndev * v_local
     update = make_vertex_update(cfg)
     eps = jnp.float32(cfg.eps)
     halt_window = cfg.halt_window
@@ -1391,27 +1438,19 @@ def make_sharded_step_fn(cfg, axis: str, ndev: int, v_local: int, plan,
         # Pregel messages: one plan-defined label exchange.
         if overlap:
             interior_fn, frontier_fn = scores
-            pending = plan.start_exchange(state.labels, aux, axis,
-                                          *plan_blocks)
+            with jax.named_scope("lpa/exchange"):
+                pending = plan.start_exchange(state.labels, aux, axis,
+                                              *plan_blocks)
             partial = interior_fn(state.labels, *score_blocks)
-            lookup, aux, xbytes = plan.finish_exchange(pending)
+            with jax.named_scope("lpa/exchange"):
+                lookup, aux, xbytes = plan.finish_exchange(pending)
         else:
-            lookup, aux, xbytes = plan.exchange(state.labels, aux, axis,
-                                                *plan_blocks)
+            with jax.named_scope("lpa/exchange"):
+                lookup, aux, xbytes = plan.exchange(state.labels, aux,
+                                                    axis, *plan_blocks)
         off = jax.lax.axis_index(axis) * v_local
-        if noise_mode == "folded":
-            k_dev = jax.random.fold_in(k_it, jax.lax.axis_index(axis))
-            k_noise, k_mig = jax.random.split(k_dev)
-            noise = jax.random.uniform(k_noise, (v_local, k), jnp.float32,
-                                       0.0, cfg.tie_noise)
-            u = jax.random.uniform(k_mig, (v_local,), jnp.float32)
-        else:
-            k_noise, k_mig = jax.random.split(k_it)
-            noise_full = jax.random.uniform(k_noise, (v_pad, k), jnp.float32,
-                                            0.0, cfg.tie_noise)
-            u_full = jax.random.uniform(k_mig, (v_pad,), jnp.float32)
-            noise = jax.lax.dynamic_slice_in_dim(noise_full, off, v_local, 0)
-            u = jax.lax.dynamic_slice_in_dim(u_full, off, v_local, 0)
+        noise, u = _draw_shard_noise(k_it, axis, noise_mode, ndev, v_local,
+                                     k, cfg.tie_noise)
         valid = off + jnp.arange(v_local, dtype=jnp.int32) < num_real
         if fused:
             fused_fn = frontier_fn if overlap else scores
@@ -1505,7 +1544,8 @@ def _sharded_program(cfg, opts: EngineOptions, mesh: Mesh, axis: str,
             blocks = tuple(r[0] if s else r for r, s in zip(rest, strip))
             score_blocks, plan_blocks = blocks[:n_score], blocks[n_score:]
             dl = deg_l[0]
-            aux0 = plan.init_aux(state.labels, axis, *plan_blocks)
+            with jax.named_scope("lpa/exchange"):
+                aux0 = plan.init_aux(state.labels, axis, *plan_blocks)
             if single_step:
                 new_state, _ = step_fn(state, aux0, capacity, num_real, dl,
                                        score_blocks, plan_blocks)
@@ -1626,7 +1666,6 @@ def make_sharded_frontier_step_fn(cfg, axis: str, ndev: int, v_local: int,
     index, which is why sharded frontier mode is XLA-backend-only.
     """
     k = cfg.k
-    v_pad = ndev * v_local
     eps = jnp.float32(cfg.eps)
     halt_window = cfg.halt_window
     propose, finish = make_update_parts(
@@ -1640,34 +1679,23 @@ def make_sharded_frontier_step_fn(cfg, axis: str, ndev: int, v_local: int,
                 plan_blocks):
         state, aux, active, prev_lookup, hist = carry
         key, k_it = jax.random.split(state.key)
-        lookup, aux, xbytes = plan.exchange(state.labels, aux, axis,
-                                            *plan_blocks)
+        with jax.named_scope("lpa/exchange"):
+            lookup, aux, xbytes = plan.exchange(state.labels, aux, axis,
+                                                *plan_blocks)
         # Expand: re-activate local endpoints of edges whose remote
         # endpoint changed label last iteration (pad edges point at a
         # fixed in-range slot, so a spurious hit only re-activates an
         # already-active migrant -- conservative, never unsound).
         src_local, dst_idx = score_blocks[0], score_blocks[1]
-        changed_dst = (lookup[dst_idx] != prev_lookup[dst_idx]
-                       ).astype(jnp.int32)
-        touched = jnp.zeros((v_local,), jnp.int32).at[src_local].max(
-            changed_dst) > 0
+        with jax.named_scope("lpa/expand"):
+            changed_dst = (lookup[dst_idx] != prev_lookup[dst_idx]
+                           ).astype(jnp.int32)
+            touched = jnp.zeros((v_local,), jnp.int32).at[src_local].max(
+                changed_dst) > 0
         active = active | touched
         off = jax.lax.axis_index(axis) * v_local
-        if noise_mode == "folded":
-            k_dev = jax.random.fold_in(k_it, jax.lax.axis_index(axis))
-            k_noise, k_mig = jax.random.split(k_dev)
-            noise = jax.random.uniform(k_noise, (v_local, k), jnp.float32,
-                                       0.0, cfg.tie_noise)
-            u = jax.random.uniform(k_mig, (v_local,), jnp.float32)
-        else:
-            k_noise, k_mig = jax.random.split(k_it)
-            noise_full = jax.random.uniform(k_noise, (v_pad, k),
-                                            jnp.float32, 0.0,
-                                            cfg.tie_noise)
-            u_full = jax.random.uniform(k_mig, (v_pad,), jnp.float32)
-            noise = jax.lax.dynamic_slice_in_dim(noise_full, off, v_local,
-                                                 0)
-            u = jax.lax.dynamic_slice_in_dim(u_full, off, v_local, 0)
+        noise, u = _draw_shard_noise(k_it, axis, noise_mode, ndev, v_local,
+                                     k, cfg.tie_noise)
         valid = (off + jnp.arange(v_local, dtype=jnp.int32) < num_real) \
             & active
         if fused:
@@ -1743,8 +1771,9 @@ def _sharded_frontier_program(cfg, opts: EngineOptions, mesh: Mesh,
             blocks = tuple(r[0] if s else r for r, s in zip(rest, strip))
             score_blocks, plan_blocks = blocks[:n_score], blocks[n_score:]
             dl = deg_l[0]
-            prev_lookup, aux0, b0 = plan.prime(state.labels, axis,
-                                               *plan_blocks)
+            with jax.named_scope("lpa/exchange"):
+                prev_lookup, aux0, b0 = plan.prime(state.labels, axis,
+                                                   *plan_blocks)
             state = state._replace(
                 exchanged_bytes=state.exchanged_bytes + b0)
 

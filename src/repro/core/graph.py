@@ -15,6 +15,8 @@ from typing import Optional, Tuple
 
 import numpy as np
 
+from . import trace
+
 
 @dataclasses.dataclass(frozen=True)
 class Graph:
@@ -73,8 +75,16 @@ def from_edges(src, dst, num_vertices: int, directed: bool = True) -> Graph:
     """Build the weighted undirected Graph per Eq. (3).
 
     w(u,v) = 2 if both (u,v) and (v,u) exist in the directed input, else 1.
-    Undirected input gets w = 1 everywhere.
+    Undirected input gets w = 1 everywhere.  Runs in the span
+    ``graph/from_edges`` (``repro.core.trace``).
     """
+    with trace.span("graph/from_edges", vertices=int(num_vertices)) as at:
+        graph = _from_edges(src, dst, num_vertices, directed)
+        at["entries"] = graph.num_directed_entries
+    return graph
+
+
+def _from_edges(src, dst, num_vertices: int, directed: bool) -> Graph:
     src = np.asarray(src, dtype=np.int32)
     dst = np.asarray(dst, dtype=np.int32)
     if src.size:

@@ -79,7 +79,7 @@ import numpy as np
 
 from . import delta as _delta
 from . import engine as _engine
-from . import metrics
+from . import metrics, trace
 from .engine import EngineOptions
 from .graph import Graph, add_edges
 from .spinner import (PartitionResult, SpinnerConfig, prepare_init,
@@ -242,7 +242,8 @@ class PartitionSession:
                   ) -> PartitionResult:
         """Run to a stable state from ``init`` (or a fresh random start)."""
         self._check_open()
-        return self._run(init, record_history, callback)
+        return self._traced("session/partition", self._run, init,
+                            record_history, callback)
 
     def adapt(self, new_graph: Optional[Graph] = None,
               prev: Optional[np.ndarray] = None, *,
@@ -275,6 +276,13 @@ class PartitionSession:
         self._check_open()
         if new_graph is not None and edge_updates is not None:
             raise ValueError("pass at most one of new_graph/edge_updates")
+        return self._traced("session/adapt", self._adapt, new_graph, prev,
+                            edge_updates, num_vertices, record_history,
+                            callback, frontier,
+                            delta=edge_updates is not None)
+
+    def _adapt(self, new_graph, prev, edge_updates, num_vertices,
+               record_history, callback, frontier) -> PartitionResult:
         batch = None
         if edge_updates is not None:
             e_src, e_dst = edge_updates
@@ -293,8 +301,9 @@ class PartitionSession:
                     return res
                 self._fallback_adapts += 1
             # fallback: the classic host rebuild (bit-identical oracle)
-            new_graph = add_edges(self.graph, e_src, e_dst,
-                                  num_vertices=num_vertices)
+            with trace.span("session/rebuild"):
+                new_graph = add_edges(self.graph, e_src, e_dst,
+                                      num_vertices=num_vertices)
             self._host_rebuilds += 1
             batch = (e_src, e_dst)
         prev = self._require_prev(prev)
@@ -441,7 +450,8 @@ class PartitionSession:
         # run first, commit the new k only on success: a rejected call
         # (bad history/callback combination) must not leave the session
         # with k_new but labels from k_old
-        res = self._run(init, record_history, callback, cfg=cfg_new)
+        res = self._traced("session/resize", self._run, init,
+                           record_history, callback, cfg_new)
         self.cfg = cfg_new
         return res
 
@@ -567,7 +577,8 @@ class PartitionSession:
         if prev.shape[0] != self._graph.num_vertices:
             return None     # shorter prev needs the -1/least-loaded init
         if self._delta is None:
-            self._delta = self._delta_init(*mode)
+            with trace.span("session/bind"):
+                self._delta = self._delta_init(*mode)
         fs = self._delta
         mp = _engine._merge_program()
         self._track(mp)
@@ -587,13 +598,14 @@ class PartitionSession:
         self._delta_bytes_total += nbytes
         self._fast_adapts += 1
 
-        key, _ = jax.random.split(jax.random.PRNGKey(self.cfg.seed))
-        lp = _engine._loads_program(self.cfg.k)
-        self._track(lp)
-        labels_p = _engine.pad_labels(jnp.asarray(prev, jnp.int32),
-                                      fs.v_pad)
-        loads = lp.run(labels_p, fs.dd.deg_w)
-        return fs, _engine.init_state(labels_p, loads, key)
+        with trace.span("session/prepare"):
+            key, _ = jax.random.split(jax.random.PRNGKey(self.cfg.seed))
+            lp = _engine._loads_program(self.cfg.k)
+            self._track(lp)
+            labels_p = _engine.pad_labels(jnp.asarray(prev, jnp.int32),
+                                          fs.v_pad)
+            loads = lp.run(labels_p, fs.dd.deg_w)
+            return fs, _engine.init_state(labels_p, loads, key)
 
     def _fast_bind(self, fs: _DeltaFast,
                    frontier: bool) -> "_engine.GraphBind":
@@ -629,10 +641,12 @@ class PartitionSession:
             if frontier:
                 prog = _engine._frontier_program(cfg, fs.opts_t)
                 self._track(prog)
-                state, hist = prog.run(state, self._active_mask(fs.v_pad),
-                                       bind)
+                with trace.span("session/dispatch"):
+                    state, hist = prog.run(
+                        state, self._active_mask(fs.v_pad), bind)
             else:
-                state = fs.prog_full.run(state, bind)
+                with trace.span("session/dispatch"):
+                    state = fs.prog_full.run(state, bind)
             eng = "fused"
         else:
             args = (jnp.float32(capacity), jnp.int32(V), dd.deg_w) \
@@ -643,10 +657,12 @@ class PartitionSession:
                     cfg, fs.opts_t, fs.mesh, fs.axis, fs.plan.signature(),
                     len(dd.score), fused=fused)
                 self._track(prog)
-                state, hist = prog.run(state, self._active_mask(fs.v_pad),
-                                       *args)
+                with trace.span("session/dispatch"):
+                    state, hist = prog.run(
+                        state, self._active_mask(fs.v_pad), *args)
             else:
-                state = fs.prog_full.run(state, *args)
+                with trace.span("session/dispatch"):
+                    state = fs.prog_full.run(state, *args)
             eng = "sharded"
         res = self._finish_state(state, V, eng, hist)
         self._dirty = None
@@ -750,21 +766,26 @@ class PartitionSession:
             active[:self._dirty.shape[0]] = self._dirty
         return jnp.asarray(active)
 
-    def _finish_state(self, state, num_real: int, eng: str,
-                      hist) -> PartitionResult:
-        iters = int(state.iteration)
-        if hist is not None:
-            per_iter = tuple(float(x) for x in np.asarray(hist)[:iters])
-            scored = float(sum(per_iter))
-        else:
-            per_iter, scored = (), -1.0
-        res = PartitionResult(
-            labels=np.asarray(state.labels)[:num_real],
-            loads=np.asarray(state.loads), iterations=iters,
-            halted=bool(state.halted), history=[],
-            total_messages=float(state.total_messages), engine=eng,
-            exchanged_bytes=float(state.exchanged_bytes),
-            scored_vertices=scored, scored_per_iter=per_iter)
+    def _finish_state(self, state, num_real: int, eng: str, hist,
+                      history: Optional[list] = None) -> PartitionResult:
+        """Wait for a run's final state (span ``session/wait``), copy it
+        to the host as this session's new result (``session/fetch``)."""
+        with trace.span("session/wait"):
+            jax.block_until_ready((state, hist))
+        with trace.span("session/fetch"):
+            iters = int(state.iteration)
+            if hist is not None:
+                per_iter = tuple(float(x) for x in np.asarray(hist)[:iters])
+                scored = float(sum(per_iter))
+            else:
+                per_iter, scored = (), -1.0
+            res = PartitionResult(
+                labels=np.asarray(state.labels)[:num_real],
+                loads=np.asarray(state.loads), iterations=iters,
+                halted=bool(state.halted), history=history or [],
+                total_messages=float(state.total_messages), engine=eng,
+                exchanged_bytes=float(state.exchanged_bytes),
+                scored_vertices=scored, scored_per_iter=per_iter)
         self._last = res
         self._prev = res.labels
         self._runs += 1
@@ -785,17 +806,19 @@ class PartitionSession:
             raise ValueError(
                 f"frontier=True requires a while_loop engine (fused/"
                 f"sharded/auto), not engine={opts.engine!r}")
-        labels, loads, key = prepare_init(graph, cfg, init)
-        if opts.mesh is not None or opts.engine == "sharded":
-            state, hist = _engine.run_sharded_frontier(
-                graph, cfg, labels, loads, key, active, mesh=opts.mesh,
-                axis=opts.axis, opts=opts, on_program=self._track)
-            eng = "sharded"
-        else:
-            state, hist = _engine.run_frontier(
-                graph, cfg, labels, loads, key, active, opts=opts,
-                on_program=self._track)
-            eng = "fused"
+        with trace.span("session/prepare"):
+            labels, loads, key = prepare_init(graph, cfg, init)
+        with trace.span("session/dispatch"):
+            if opts.mesh is not None or opts.engine == "sharded":
+                state, hist = _engine.run_sharded_frontier(
+                    graph, cfg, labels, loads, key, active, mesh=opts.mesh,
+                    axis=opts.axis, opts=opts, on_program=self._track)
+                eng = "sharded"
+            else:
+                state, hist = _engine.run_frontier(
+                    graph, cfg, labels, loads, key, active, opts=opts,
+                    on_program=self._track)
+                eng = "fused"
         res = self._finish_state(state, graph.num_vertices, eng, hist)
         self._dirty = None
         return res
@@ -983,8 +1006,31 @@ class PartitionSession:
                              "partition() first or pass prev=")
         return np.asarray(prev, dtype=np.int32)
 
+    def _traced(self, name: str, call: Callable, *args,
+                delta: bool = False) -> PartitionResult:
+        """``call(*args)`` in span ``name`` (``repro.core.trace``), with
+        the result's ``iterations``, ``halted`` and ``engine`` and the
+        call's ``compiles`` (the growth of ``self.compiles``) as attrs;
+        a ``delta`` call adds ``fast`` (it took the O(|delta|) path) and
+        that path's ``upload_bytes``."""
+        compiles, fast = self.compiles, self._fast_adapts
+        with trace.span(name) as attrs:
+            res = call(*args)
+            attrs.update(iterations=res.iterations, halted=res.halted,
+                         engine=res.engine,
+                         compiles=self.compiles - compiles)
+            if delta:
+                attrs["fast"] = self._fast_adapts > fast
+                attrs["upload_bytes"] = (self._delta_bytes_last
+                                         if attrs["fast"] else 0)
+        return res
+
     def _run(self, init, record_history, callback,
              cfg: Optional[SpinnerConfig] = None) -> PartitionResult:
+        """One run on the current graph, in the spans ``session/prepare``
+        (initial labels and loads), ``session/bind`` (the runner: on a
+        graph's first call its padded layout and edge upload),
+        ``session/dispatch``, ``session/wait`` and ``session/fetch``."""
         self._check_open()
         graph, opts = self.graph, self.options
         cfg = self.cfg if cfg is None else cfg
@@ -1003,12 +1049,7 @@ class PartitionSession:
             raise ValueError(
                 f"unknown engine {eng!r}; "
                 "available: auto, fused, sharded, chunked, host")
-
-        labels, loads, key = prepare_init(graph, cfg, init)
-        if eng == "host":
-            res = self._run_host(cfg, labels, loads, key,
-                                 record_history is not False, callback)
-        elif eng in ("fused", "sharded"):
+        if eng in ("fused", "sharded"):
             # "chunked" is single-device only, so on a mesh there is no
             # per-iteration visibility at all -- say so instead of pointing
             # at an option the mesh check forbids.
@@ -1024,43 +1065,64 @@ class PartitionSession:
                 raise ValueError(
                     f"engine={eng!r} cannot record per-iteration history; "
                     f"{remedy}")
-            if eng == "sharded":
-                state = _engine.run_sharded(graph, cfg, labels, loads, key,
-                                            mesh=opts.mesh, axis=opts.axis,
-                                            opts=opts,
-                                            on_program=self._track)
-            else:
-                state = _engine.run_fused(graph, cfg, labels, loads, key,
-                                          opts=opts, on_program=self._track)
-            history = []
-        else:   # chunked
-            record = record_history is not False
-            state, history = _engine.run_chunked(
-                graph, cfg, labels, loads, key,
-                chunk_size=opts.chunk_size or _engine.DEFAULT_CHUNK,
-                callback=callback, record=record, opts=opts,
-                on_program=self._track)
-            if not record:
-                history = []     # callback may force recording internally
-        if eng != "host":
-            # sharded labels come back padded to the sharded layout
-            res = PartitionResult(
-                labels=np.asarray(state.labels)[:graph.num_vertices],
-                loads=np.asarray(state.loads),
-                iterations=int(state.iteration),
-                halted=bool(state.halted), history=history,
-                total_messages=float(state.total_messages),
-                engine=eng,
-                exchanged_bytes=float(state.exchanged_bytes))
 
-        self._last = res
-        self._prev = res.labels
-        self._runs += 1
+        with trace.span("session/prepare"):
+            labels, loads, key = prepare_init(graph, cfg, init)
+        with trace.span("session/bind"):
+            run = self._runner(eng, graph, cfg, record_history, callback)
+        with trace.span("session/dispatch"):
+            state, history = run(labels, loads, key)
+        # sharded labels come back padded to the sharded layout
+        res = self._finish_state(state, graph.num_vertices, eng, None,
+                                 history)
         self._dirty = None     # a full run reconverges every vertex
         return res
 
-    def _run_host(self, cfg, labels, loads, key, record_history: bool,
-                  callback) -> PartitionResult:
+    def _runner(self, eng: str, graph: Graph, cfg: SpinnerConfig,
+                record_history, callback) -> Callable:
+        """Bind ``graph`` to engine ``eng``'s program (cached per graph
+        and static configuration); ``run(labels, loads, key) -> (state,
+        history)``.  The fused and sharded runs are one asynchronous
+        dispatch; chunked and host runs sync per chunk or iteration."""
+        opts = self.options
+        if eng == "fused":
+            runner = _engine.make_fused_runner(graph, cfg, opts=opts)
+            self._track(getattr(runner, "program", None))
+            return lambda labels, loads, key: (
+                runner(_engine.init_state(labels, loads, key)), [])
+        if eng == "sharded":
+            mesh = opts.mesh
+            if mesh is None:
+                mesh = _engine._default_partition_mesh()
+            runner = _engine.make_sharded_runner(graph, cfg, mesh,
+                                                 opts.axis, opts=opts)
+            self._track(getattr(runner, "program", None))
+            v_pad = _engine.sharded_v_pad(graph, opts, mesh, opts.axis)
+            return lambda labels, loads, key: (runner(_engine.init_state(
+                _engine.pad_labels(labels, v_pad), loads, key)), [])
+        if eng == "host":
+            step = _engine.make_host_step(graph, cfg, opts)
+            self._track(step.program)
+            return lambda labels, loads, key: self._host_loop(
+                step, graph, cfg, labels, loads, key,
+                record_history is not False, callback)
+        record = record_history is not False
+        recorded = record or callback is not None   # a callback needs it
+        chunk = opts.chunk_size or _engine.DEFAULT_CHUNK
+        run_chunk = _engine.make_chunked_runner(graph, cfg, chunk,
+                                                record=recorded, opts=opts)
+        self._track(getattr(run_chunk, "program", None))
+
+        def run(labels, loads, key):
+            state, history = _engine.drive_chunks(
+                run_chunk, _engine.init_state(labels, loads, key), cfg,
+                chunk, recorded, callback)
+            return state, history if record else []
+
+        return run
+
+    def _host_loop(self, step, graph, cfg, labels, loads, key,
+                   record_history: bool, callback) -> tuple:
         """Legacy per-iteration host loop -- the fused engines' oracle.
 
         Runs the same padded layout and jitted step program as the fused
@@ -1068,11 +1130,8 @@ class PartitionSession:
         on-device ``engine._halting_update`` bit for bit), so host and
         fused engines agree on iteration counts, not just trajectories.
         ``cfg`` arrives from ``_run`` (resize runs the new k before
-        committing it to the session).
+        committing it to the session).  Returns ``(state, history)``.
         """
-        graph, opts = self.graph, self.options
-        step = _engine.make_host_step(graph, cfg, opts)
-        self._track(step.program)
         num_real = graph.num_vertices
         labels = _engine.pad_labels(labels, step.v_pad)
         best_score = np.float32(-np.inf)
@@ -1119,13 +1178,9 @@ class PartitionSession:
                 if stall >= cfg.halt_window:
                     halted = True
                     break
-
-        return PartitionResult(labels=np.asarray(labels)[:num_real],
-                               loads=np.asarray(loads),
-                               iterations=it, halted=halted,
-                               history=history,
-                               total_messages=total_messages,
-                               engine="host")
+        state = _engine.init_state(labels, loads, key)._replace(
+            iteration=it, halted=halted, total_messages=total_messages)
+        return state, history
 
 
 def open_session(graph: Graph, cfg: SpinnerConfig,

@@ -212,9 +212,9 @@ class XlaScatterBackend:
         unsharded path.
         """
         def scores(lookup, src_local, dst_idx, w):
-            nbr = lookup[dst_idx]
-            return jnp.zeros((v_local, k),
-                             jnp.float32).at[src_local, nbr].add(w)
+            return ref.scatter_scores(
+                jnp.zeros((v_local, k), jnp.float32), src_local, lookup,
+                dst_idx, w)
         return scores
 
     def sharded_graph_args(self, sg, k: int, dst_index: np.ndarray,
@@ -228,13 +228,13 @@ class XlaScatterBackend:
         (see the protocol docstring): the interior half reads the local
         label shard, the frontier half the exchange plan's lookup."""
         def interior(labels_local, src_i, dst_i, w_i, src_f, dst_f, w_f):
-            nbr = labels_local[dst_i]
-            return jnp.zeros((v_local, k),
-                             jnp.float32).at[src_i, nbr].add(w_i)
+            return ref.scatter_scores(
+                jnp.zeros((v_local, k), jnp.float32), src_i, labels_local,
+                dst_i, w_i)
 
         def frontier(partial, lookup, src_i, dst_i, w_i, src_f, dst_f,
                      w_f):
-            return partial.at[src_f, lookup[dst_f]].add(w_f)
+            return ref.scatter_scores(partial, src_f, lookup, dst_f, w_f)
 
         return interior, frontier
 
@@ -287,9 +287,9 @@ class XlaScatterBackend:
 
         def fused(lookup, labels, deg_w, loads, noise, u, valid, reduce_,
                   C, src_local, dst_idx, w):
-            nbr = lookup[dst_idx]
-            scores = jnp.zeros((v_local, k),
-                               jnp.float32).at[src_local, nbr].add(w)
+            scores = ref.scatter_scores(
+                jnp.zeros((v_local, k), jnp.float32), src_local, lookup,
+                dst_idx, w)
             best, tb, tc, m = propose(scores, labels, deg_w, loads, noise,
                                       valid, C)
             out = finish(best, tb, tc, m, labels, deg_w, loads, u, valid,
@@ -311,14 +311,14 @@ class XlaScatterBackend:
             k, degree_weighted=degree_weighted, current_bonus=current_bonus)
 
         def interior(labels_local, src_i, dst_i, w_i, src_f, dst_f, w_f):
-            nbr = labels_local[dst_i]
-            return jnp.zeros((v_local, k),
-                             jnp.float32).at[src_i, nbr].add(w_i)
+            return ref.scatter_scores(
+                jnp.zeros((v_local, k), jnp.float32), src_i, labels_local,
+                dst_i, w_i)
 
         def frontier(partial, lookup, labels, deg_w, loads, noise, u,
                      valid, reduce_, C, src_i, dst_i, w_i, src_f, dst_f,
                      w_f):
-            scores = partial.at[src_f, lookup[dst_f]].add(w_f)
+            scores = ref.scatter_scores(partial, src_f, lookup, dst_f, w_f)
             best, tb, tc, m = propose(scores, labels, deg_w, loads, noise,
                                       valid, C)
             return finish(best, tb, tc, m, labels, deg_w, loads, u, valid,
@@ -503,9 +503,13 @@ class PallasTiledBackend:
 
         def interior(labels_local, si, di, wi, sf, df, wf, perm, inv_perm,
                      deg_t):
-            return spinner_scores_pallas(si, labels_local[di], wi,
-                                         tile_v=self.tile_v, k_pad=k_pad,
-                                         interpret=interpret)
+            with jax.named_scope("lpa/gather"):
+                dst_label = labels_local[di]
+            with jax.named_scope("lpa/scatter"):
+                return spinner_scores_pallas(si, dst_label, wi,
+                                             tile_v=self.tile_v,
+                                             k_pad=k_pad,
+                                             interpret=interpret)
 
         def frontier(partial, lookup, labels, deg_w, loads, noise, u,
                      valid, reduce_, C, si, di, wi, sf, df, wf, perm,

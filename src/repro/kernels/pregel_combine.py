@@ -166,6 +166,7 @@ def pregel_reduce_pallas(src_local: jax.Array, msg: jax.Array,
         args.append(acc_init.astype(dtype)[:, None, :])
     out = pl.pallas_call(
         kernel,
+        name="pregel_combine",
         grid=(t, c),
         in_specs=in_specs,
         out_specs=r_spec,
@@ -209,6 +210,7 @@ def pregel_combine_pallas(src_local: jax.Array, msg: jax.Array,
         args.append(acc_init.astype(dtype)[:, None, :])
     out, chg = pl.pallas_call(
         kernel,
+        name="pregel_combine_fused",
         grid=(t, c),
         in_specs=in_specs,
         out_specs=[r_spec, r_spec],

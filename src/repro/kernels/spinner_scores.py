@@ -117,11 +117,13 @@ def scores_from_tiles(labels_lookup: jax.Array, src_local: jax.Array,
     tiled rows back to vertex order.  Pure and trace-friendly, so it
     inlines into ``lax.while_loop`` bodies on either path.
     """
-    dst_label = labels_lookup[dst]               # gather (T, C, TILE_E)
-    scores_pad = spinner_scores_pallas(src_local, dst_label, w,
-                                       tile_v=tile_v, k_pad=k_pad,
-                                       interpret=interpret)
-    return scores_pad[perm, :k]
+    with jax.named_scope("lpa/gather"):
+        dst_label = labels_lookup[dst]           # gather (T, C, TILE_E)
+    with jax.named_scope("lpa/scatter"):
+        scores_pad = spinner_scores_pallas(src_local, dst_label, w,
+                                           tile_v=tile_v, k_pad=k_pad,
+                                           interpret=interpret)
+        return scores_pad[perm, :k]
 
 
 def spinner_scores_pallas(src_local: jax.Array, dst_label: jax.Array,
@@ -143,6 +145,7 @@ def spinner_scores_pallas(src_local: jax.Array, dst_label: jax.Array,
     kernel = functools.partial(_kernel, tile_v=tile_v, k_pad=k_pad)
     return pl.pallas_call(
         kernel,
+        name="spinner_scores",
         grid=(t, c),
         in_specs=[edge_spec(tile_e)] * 3,
         out_specs=pl.BlockSpec((tile_v, k_pad), lambda i, j: (i, 0)),
@@ -314,6 +317,7 @@ def fused_update_pallas(src_local: jax.Array, dst_label: jax.Array,
         inputs.append(tile_act[:, None, :])
     best, tb, tc, m = pl.pallas_call(
         kernel,
+        name="spinner_fused_update",
         grid=(t, c),
         in_specs=in_specs,
         out_specs=[r_spec, r_spec, r_spec, k_spec],
@@ -359,21 +363,24 @@ def fused_update_from_tiles(labels_lookup: jax.Array, labels: jax.Array,
     f32 vectors in vertex order plus the (k,) local M(l) partial, i.e.
     exactly the contract of ``engine.make_update_parts``'s ``propose``.
     """
-    dst_label = labels_lookup[dst]               # gather (T, C, TILE_E)
-    t = src_local.shape[0]
-    inv_safe = jnp.maximum(inv_perm, 0)
-    labels_t = labels[inv_safe].reshape(t, tile_v)
-    valid_t = ((inv_perm >= 0) & valid[inv_safe]).astype(
-        jnp.int32).reshape(t, tile_v)
-    if k_pad != k:
-        noise = jnp.pad(noise, ((0, 0), (0, k_pad - k)))
-        penalty = jnp.pad(penalty, (0, k_pad - k))
-    noise_t = noise[inv_safe]
-    tile_act = jnp.max(valid_t, axis=1, keepdims=True) if frontier else None
-    best_t, tb_t, tc_t, m = fused_update_pallas(
-        src_local, dst_label, w, labels_t, jnp.asarray(deg_t), valid_t,
-        penalty[None, :], noise_t, tile_v=tile_v, k_pad=k_pad, k=k,
-        current_bonus=current_bonus, degree_weighted=degree_weighted,
-        interpret=interpret, acc_init=acc_init, tile_act=tile_act)
-    return (best_t.reshape(-1)[perm], tb_t.reshape(-1)[perm],
-            tc_t.reshape(-1)[perm], m[0, :k])
+    with jax.named_scope("lpa/gather"):
+        dst_label = labels_lookup[dst]           # gather (T, C, TILE_E)
+    with jax.named_scope("lpa/propose"):
+        t = src_local.shape[0]
+        inv_safe = jnp.maximum(inv_perm, 0)
+        labels_t = labels[inv_safe].reshape(t, tile_v)
+        valid_t = ((inv_perm >= 0) & valid[inv_safe]).astype(
+            jnp.int32).reshape(t, tile_v)
+        if k_pad != k:
+            noise = jnp.pad(noise, ((0, 0), (0, k_pad - k)))
+            penalty = jnp.pad(penalty, (0, k_pad - k))
+        noise_t = noise[inv_safe]
+        tile_act = (jnp.max(valid_t, axis=1, keepdims=True) if frontier
+                    else None)
+        best_t, tb_t, tc_t, m = fused_update_pallas(
+            src_local, dst_label, w, labels_t, jnp.asarray(deg_t), valid_t,
+            penalty[None, :], noise_t, tile_v=tile_v, k_pad=k_pad, k=k,
+            current_bonus=current_bonus, degree_weighted=degree_weighted,
+            interpret=interpret, acc_init=acc_init, tile_act=tile_act)
+        return (best_t.reshape(-1)[perm], tb_t.reshape(-1)[perm],
+                tc_t.reshape(-1)[perm], m[0, :k])

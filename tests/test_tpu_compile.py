@@ -8,6 +8,10 @@ topology.  The TPU compiler refuses here what the chip would refuse
 (block shapes off the (8, 128) tiling, unknown compiler parameters, VMEM
 overflow); interpret mode on the CPU sees none of it.
 
+One more case compiles the XLA score path at the widths of the benchmark's
+1M-vertex graph and checks that the TPU compiler's sort and scatter keep
+the ``lpa/scatter`` scope the program gives them (``kernels/ref.py``).
+
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, so the test workers must
 all collect the same tests and only the one running this file loads it.
@@ -21,6 +25,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.pregel_combine import (pregel_combine_pallas,
                                           pregel_reduce_pallas)
+from repro.kernels.ref import spinner_scores_ref
 from repro.kernels.spinner_scores import (fused_update_pallas,
                                           spinner_scores_pallas)
 
@@ -114,3 +119,27 @@ def test_kernel_compiles_for_v5e(one_chip, variant):
             for shape, dtype in specs]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_xla_scores_keep_their_scopes_on_v5e(one_chip):
+    """Every sort, scatter and gather the compiler emits for the score
+    path names its scope: a 2-D scatter would lose it to the sort-based
+    rewrite, and 30-40% of an LPA iteration's device time with it."""
+    import re
+    v, e, k = 1_048_576, 33_554_432, 64
+
+    def fn(labels, src, dst, w):
+        return spinner_scores_ref(labels, src, dst, w, v, k)
+
+    args = [jax.ShapeDtypeStruct((n,), dt, sharding=one_chip)
+            for n, dt in ((v, jnp.int32), (e, jnp.int32), (e, jnp.int32),
+                          (e, jnp.float32))]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    ops = [line for line in text.splitlines()
+           if re.search(r"\b(sort|scatter|gather)\(", line)
+           or "kind=kCustom" in line]
+    assert ops
+    for line in ops:
+        name = re.search(r'op_name="([^"]*)"', line)
+        assert name and re.search(r"lpa/(gather|scatter)", name.group(1)), \
+            line[:160]
