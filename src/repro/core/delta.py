@@ -291,6 +291,7 @@ class DeviceDelta:
     # --- single-device COO (and the pallas frontier COO) ---
     next_slot: int = 0         # first free tail slot of the padded COO
     e_capacity: int = 0        # total COO slots (the edge bucket)
+    csr_entries: int = 0       # single_xla: entries in CSR order (the base)
     # --- single_pallas tiled layout ---
     tile_v: int = 0
     region: int = 0            # max_chunks * tile_e slots per tile
@@ -306,11 +307,14 @@ class DeviceDelta:
 
 def init_single_xla(score_args: tuple, deg_w: jax.Array,
                     num_entries: int) -> DeviceDelta:
-    """Mode A: the padded COO upload; slack = pad_graph's tail filler."""
-    src, dst, w = score_args
-    return DeviceDelta(mode="single_xla", score=(src, dst, w), deg_w=deg_w,
-                       next_slot=int(num_entries),
-                       e_capacity=int(src.shape[0]))
+    """Mode A: the padded COO upload; slack = pad_graph's tail filler.
+    ``score_args`` is the XLA backend's ``(src, dst, w, row_ptr,
+    merged)``; a merge writes the first three and counts its entries
+    into ``merged``, which turns the score pass to the forward one."""
+    return DeviceDelta(mode="single_xla", score=tuple(score_args),
+                       deg_w=deg_w, next_slot=int(num_entries),
+                       e_capacity=int(score_args[0].shape[0]),
+                       csr_entries=int(num_entries))
 
 
 def init_single_pallas(score_args: tuple, deg_w: jax.Array, coo: tuple,
@@ -451,15 +455,17 @@ def apply_batch(dd: DeviceDelta, plan: BatchPlan, slotting,
                                   int(dd.score[0].size))[0])
             vs, vd, vw = (dev(a) for a in _bucket_pad(
                 [(src32, False), (dst32, False), (dw32, False)], n, 0))
-            set_groups = ((dd.score, idx, (vs, vd, vw)),)
+            set_groups = ((dd.score[:3], idx, (vs, vd, vw)),)
             didx = dev(_bucket_pad([(plan.src.astype(np.int64), True)], n,
                                    int(dd.deg_w.size))[0])
             add_groups = ((dd.deg_w, didx, vw),)
+            n_merged = dev(np.int32(dd.next_slot + n - dd.csr_entries))
 
             def unpack(merged):
                 (new_score,), (new_deg,) = merged
-                return dataclasses.replace(dd, score=tuple(new_score),
-                                           deg_w=new_deg)
+                return dataclasses.replace(
+                    dd, score=tuple(new_score) + (dd.score[3], n_merged),
+                    deg_w=new_deg)
         elif dd.mode == "single_pallas":
             tile_slots, coo_slots = slots
             sl_local = (dd.perm[plan.src] % dd.tile_v).astype(np.int32)

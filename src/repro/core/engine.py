@@ -332,7 +332,8 @@ class GraphBind(NamedTuple):
     deg_w: jax.Array           # (V_pad,) f32 weighted degrees (0 on pads)
     capacity: jax.Array        # f32 scalar C (Eq. 5) of the REAL graph
     num_real: jax.Array        # int32 scalar: vertices < num_real are real
-    score: tuple               # score backend's edge arrays
+    score: tuple               # score backend's edge arrays (XLA: with
+                               # row_ptr and the merged-entry count)
     hist: tuple = ()           # (src, dst, w, ideal, real_e) for history
     frontier: tuple = ()       # (src, dst) COO expansion index, frontier mode
 
@@ -1061,6 +1062,23 @@ def batch_signature(cfg, opts: EngineOptions, bind: GraphBind) -> tuple:
             opts.resolved_fused_update() == "on", shapes)
 
 
+def _lane_step(step_fn: Callable, states: SpinnerState,
+               binds: GraphBind) -> SpinnerState:
+    """``vmap(step_fn)`` over stacked lanes, except that a score arg that
+    is a scalar per lane -- the XLA backend's count of entries merged out
+    of CSR order -- goes in unbatched as its largest lane value.  A
+    ``lax.cond`` on it then stays a branch, where a batched predicate
+    would become a select that runs the forward gather in every lane; a
+    batch with any merged lane takes the forward pass, which is exact for
+    every lane."""
+    lane_flag = tuple(a.ndim == 1 for a in binds.score)
+    binds = binds._replace(score=tuple(
+        jnp.max(a) if flag else a for a, flag in zip(binds.score, lane_flag)))
+    axes = jax.tree_util.tree_map(lambda _: 0, binds)._replace(
+        score=tuple(None if flag else 0 for flag in lane_flag))
+    return jax.vmap(step_fn, in_axes=(0, axes))(states, binds)
+
+
 def _batched_program(cfg, opts: EngineOptions, nb: int) -> Program:
     """``run(states, binds) -> states``: ``nb`` independent fused runs as
     ONE while_loop dispatch over a leading batch dimension.
@@ -1085,11 +1103,10 @@ def _batched_program(cfg, opts: EngineOptions, nb: int) -> Program:
                                    s.iteration < max_iters)
 
         v_active = jax.vmap(active)
-        v_step = jax.vmap(step_fn)
 
         def body(states: SpinnerState, binds: GraphBind) -> SpinnerState:
             act = v_active(states)
-            new = v_step(states, binds)
+            new = _lane_step(step_fn, states, binds)
 
             def freeze(n, o):
                 return jnp.where(act.reshape((nb,) + (1,) * (n.ndim - 1)),

@@ -137,6 +137,8 @@ class PartitionSession:
         self._host_rebuilds = 0
         self._delta_bytes_last = 0
         self._delta_bytes_total = 0
+        self._score_pass: Optional[str] = None   # this call's (_note_pass)
+        self._score_passes = {"transposed": 0, "forward": 0}
         self.graph = graph
         self.cfg = cfg
         self.options = opts
@@ -636,6 +638,8 @@ class PartitionSession:
         capacity = cfg.c * fs.tracker.total_weight / cfg.k
         dd = fs.dd
         hist = None
+        self._note_pass(sharded=fs.mode == "sharded",
+                        merged=dd.next_slot - dd.csr_entries)
         if fs.mode == "single":
             bind = self._fast_bind(fs, bool(frontier))
             if frontier:
@@ -760,6 +764,17 @@ class PartitionSession:
         self._dirty = None
         return res
 
+    def _note_pass(self, sharded: bool, merged: int = 0) -> None:
+        """Note the score pass of the call's program, from the host's own
+        merged-entry count (no device read): ``"transposed"`` on the XLA
+        backend's single-device CSR-ordered arrays
+        (``repro.kernels.ops.xla_scores``); ``"forward"``, a label gather
+        per entry, on arrays a fast adapt merged into, on a mesh and on
+        the Pallas kernels."""
+        xla = getattr(self.options.backend(), "name", None) == "xla"
+        self._score_pass = ("transposed" if xla and not sharded
+                            and not merged else "forward")
+
     def _active_mask(self, v_pad: int) -> jax.Array:
         active = np.zeros(v_pad, bool)
         if self._dirty is not None:
@@ -806,10 +821,12 @@ class PartitionSession:
             raise ValueError(
                 f"frontier=True requires a while_loop engine (fused/"
                 f"sharded/auto), not engine={opts.engine!r}")
+        sharded = opts.mesh is not None or opts.engine == "sharded"
+        self._note_pass(sharded=sharded)
         with trace.span("session/prepare"):
             labels, loads, key = prepare_init(graph, cfg, init)
         with trace.span("session/dispatch"):
-            if opts.mesh is not None or opts.engine == "sharded":
+            if sharded:
                 state, hist = _engine.run_sharded_frontier(
                     graph, cfg, labels, loads, key, active, mesh=opts.mesh,
                     axis=opts.axis, opts=opts, on_program=self._track)
@@ -955,6 +972,7 @@ class PartitionSession:
                              padded.num_directed_entries),
             "runs": self._runs,
             "compiles": self.compiles,
+            "score_pass": dict(self._score_passes),
             "programs": len(self._programs),
             "staged": (self._staged.num_vertices
                        if self._staged is not None else None),
@@ -1009,16 +1027,20 @@ class PartitionSession:
     def _traced(self, name: str, call: Callable, *args,
                 delta: bool = False) -> PartitionResult:
         """``call(*args)`` in span ``name`` (``repro.core.trace``), with
-        the result's ``iterations``, ``halted`` and ``engine`` and the
-        call's ``compiles`` (the growth of ``self.compiles``) as attrs;
+        the result's ``iterations``, ``halted`` and ``engine``, the
+        call's ``compiles`` (the growth of ``self.compiles``) and its
+        ``score_pass`` (``_note_pass``, counted in ``stats()``) as attrs;
         a ``delta`` call adds ``fast`` (it took the O(|delta|) path) and
         that path's ``upload_bytes``."""
         compiles, fast = self.compiles, self._fast_adapts
+        self._score_pass = None
         with trace.span(name) as attrs:
             res = call(*args)
             attrs.update(iterations=res.iterations, halted=res.halted,
                          engine=res.engine,
-                         compiles=self.compiles - compiles)
+                         compiles=self.compiles - compiles,
+                         score_pass=self._score_pass)
+            self._score_passes[self._score_pass] += 1
             if delta:
                 attrs["fast"] = self._fast_adapts > fast
                 attrs["upload_bytes"] = (self._delta_bytes_last
@@ -1066,6 +1088,7 @@ class PartitionSession:
                     f"engine={eng!r} cannot record per-iteration history; "
                     f"{remedy}")
 
+        self._note_pass(sharded=eng == "sharded")
         with trace.span("session/prepare"):
             labels, loads, key = prepare_init(graph, cfg, init)
         with trace.span("session/bind"):

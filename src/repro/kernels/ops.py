@@ -182,9 +182,67 @@ def _split_dst_views(sg, dst_index) -> tuple:
     return d_int, d_fro
 
 
+def _blocked_cumsum(x: jax.Array, block: int = 128) -> jax.Array:
+    """``jnp.cumsum`` of a 1-D array as sums within ``block``-wide rows
+    plus the carried sums of the rows before.  The TPU compiler rewrites
+    a longer scan into this tree itself, but its ops then carry no op
+    name and their device time falls outside every scope."""
+    n = x.shape[0]
+    if n <= block:
+        return jnp.cumsum(x.reshape(1, n), axis=1).reshape(n)
+    rows = jnp.pad(x, (0, -n % block)).reshape(-1, block)
+    inner = jnp.cumsum(rows, axis=1)
+    totals = inner[:, -1]
+    before = _blocked_cumsum(totals, block) - totals
+    return (inner + before[:, None]).reshape(-1)[:n]
+
+
+def transposed_scores(labels: jax.Array, dst: jax.Array, w: jax.Array,
+                      row_ptr: jax.Array, k: int) -> jax.Array:
+    """``spinner_scores_ref`` over a symmetric CSR-ordered edge list,
+    bit for bit, without gathering a label per entry.
+
+    Each entry (a, b, w) scores ``[b, labels[a]]`` -- its twin's score
+    -- so its label is its own row's: a run-length expansion of
+    ``labels`` over ``row_ptr`` (scope ``lpa/gather``), a scatter of the
+    label steps ``labels[v] - labels[v - 1]`` at the sorted row starts
+    and a cumulative sum along the entries.  Empty rows telescope; a row
+    start at the end of the list is dropped.  The scatter-add then sees
+    the same multiset of (flat index, weight) pairs as the forward pass,
+    and the integer weights make its sums exact in any order.
+    """
+    with jax.named_scope("lpa/gather"):
+        steps = jnp.diff(labels, prepend=jnp.zeros((1,), labels.dtype))
+        marks = jnp.zeros(dst.shape, labels.dtype).at[row_ptr[:-1]].add(
+            steps, mode="drop", indices_are_sorted=True)
+        row_label = _blocked_cumsum(marks)
+    return ref.add_scores(jnp.zeros((labels.shape[0], k), jnp.float32),
+                          dst, row_label, w)
+
+
+def xla_scores(labels: jax.Array, src: jax.Array, dst: jax.Array,
+               w: jax.Array, row_ptr: jax.Array, merged: jax.Array,
+               k: int) -> jax.Array:
+    """The XLA backend's (V, k) scores: the transposed pass while the
+    edge arrays are in CSR order (``merged == 0``), else the forward
+    gather + scatter-add of ``spinner_scores_ref``.  ``merged`` counts
+    the entries a session's delta merge wrote into the slack tail out of
+    that order (``repro.core.delta``); both passes live in one program,
+    so a merge compiles nothing new."""
+    return jax.lax.cond(
+        merged == 0,
+        lambda: transposed_scores(labels, dst, w, row_ptr, k),
+        lambda: ref.spinner_scores_ref(labels, src, dst, w,
+                                       labels.shape[0], k))
+
+
 @dataclasses.dataclass(frozen=True)
 class XlaScatterBackend:
-    """ComputeScores via XLA scatter-add -- the Pallas kernel's oracle."""
+    """ComputeScores via XLA scatter-add -- the Pallas kernel's oracle.
+
+    Its single-device args are ``(src, dst, w, row_ptr, merged)``: the
+    padded CSR upload, its int32 row offsets and the count of entries
+    merged out of CSR order (0 here; see ``xla_scores``)."""
 
     name: str = "xla"
 
@@ -192,15 +250,15 @@ class XlaScatterBackend:
         return ("xla",)
 
     def make_scores(self, k: int) -> Callable:
-        def scores(labels, src, dst, w):
-            return ref.spinner_scores_ref(labels, src, dst, w,
-                                          labels.shape[0], k)
+        def scores(labels, src, dst, w, row_ptr, merged):
+            return xla_scores(labels, src, dst, w, row_ptr, merged, k)
         return scores
 
     def graph_args(self, graph: Graph, k: int, pad: bool = False) -> tuple:
         from repro.core.engine import device_edges   # shared upload cache
         src, dst, w, _ = device_edges(graph)
-        return (src, dst, w)
+        return (src, dst, w, jnp.asarray(graph.row_ptr, jnp.int32),
+                jnp.int32(0))
 
     def make_sharded_scores(self, k: int, v_local: int) -> Callable:
         """Local scatter-add over this device's edge shard.
@@ -259,9 +317,8 @@ class XlaScatterBackend:
             k, degree_weighted=degree_weighted, current_bonus=current_bonus)
 
         def fused(lookup, labels, deg_w, loads, noise, u, valid, reduce_,
-                  C, src, dst, w):
-            scores = ref.spinner_scores_ref(lookup, src, dst, w,
-                                            labels.shape[0], k)
+                  C, src, dst, w, row_ptr, merged):
+            scores = xla_scores(lookup, src, dst, w, row_ptr, merged, k)
             best, tb, tc, m = propose(scores, labels, deg_w, loads, noise,
                                       valid, C)
             out = finish(best, tb, tc, m, labels, deg_w, loads, u, valid,

@@ -18,11 +18,18 @@ def scatter_scores(out: jax.Array, rows: jax.Array, lookup: jax.Array,
     order and both forms give the same scores bit for bit."""
     with jax.named_scope("lpa/gather"):
         nbr = lookup[dst]
+    return add_scores(out, rows, nbr, w)
+
+
+def add_scores(out: jax.Array, rows: jax.Array, cols: jax.Array,
+               w: jax.Array) -> jax.Array:
+    """``out[rows, cols] += w`` (device scope ``lpa/scatter``): the
+    scatter-add half of ``scatter_scores``."""
     with jax.named_scope("lpa/scatter"):
         v, k = out.shape
         if v * k >= 2 ** 31:
-            return out.at[rows, nbr].add(w)
-        return out.reshape(-1).at[rows * k + nbr].add(w).reshape(v, k)
+            return out.at[rows, cols].add(w)
+        return out.reshape(-1).at[rows * k + cols].add(w).reshape(v, k)
 
 
 def spinner_scores_ref(labels: jax.Array, src: jax.Array, dst: jax.Array,

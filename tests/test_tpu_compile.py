@@ -8,9 +8,11 @@ topology.  The TPU compiler refuses here what the chip would refuse
 (block shapes off the (8, 128) tiling, unknown compiler parameters, VMEM
 overflow); interpret mode on the CPU sees none of it.
 
-One more case compiles the XLA score path at the widths of the benchmark's
-1M-vertex graph and checks that the TPU compiler's sort and scatter keep
-the ``lpa/scatter`` scope the program gives them (``kernels/ref.py``).
+Two more cases compile the XLA score passes at the widths of the
+benchmark's 1M-vertex graph and check that the TPU compiler's sort and
+scatter keep the ``lpa/scatter`` scope the program gives them
+(``kernels/ref.py``), and that the transposed pass (``kernels/ops.py``)
+reads no entry's label through a gather.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, so the test workers must
@@ -25,6 +27,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.pregel_combine import (pregel_combine_pallas,
                                           pregel_reduce_pallas)
+from repro.kernels.ops import transposed_scores
 from repro.kernels.ref import spinner_scores_ref
 from repro.kernels.spinner_scores import (fused_update_pallas,
                                           spinner_scores_pallas)
@@ -143,3 +146,31 @@ def test_xla_scores_keep_their_scopes_on_v5e(one_chip):
         name = re.search(r'op_name="([^"]*)"', line)
         assert name and re.search(r"lpa/(gather|scatter)", name.group(1)), \
             line[:160]
+
+
+def test_transposed_scores_gather_nothing_on_v5e(one_chip):
+    """The transposed pass at the benchmark's widths: no gather over the
+    33.5M entries, the row expansion under ``lpa/gather`` and the sort
+    and scatter-add under ``lpa/scatter``."""
+    import re
+    v, e, k = 1_048_576, 33_554_432, 64
+
+    def fn(labels, dst, w, row_ptr):
+        return transposed_scores(labels, dst, w, row_ptr, k)
+
+    args = [jax.ShapeDtypeStruct((n,), dt, sharding=one_chip)
+            for n, dt in ((v, jnp.int32), (e, jnp.int32), (e, jnp.float32),
+                          (v + 1, jnp.int32))]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    lines = text.splitlines()
+    assert not [line for line in lines if re.search(r"\bgather\(", line)]
+    scoped = {}
+    for line in lines:
+        op = re.search(r"\b(sort|scatter|reduce-window)\(", line)
+        if op:
+            name = re.search(r'op_name="[^"]*(lpa/(gather|scatter))', line)
+            assert name, line[:160]
+            scoped.setdefault(op.group(1), set()).add(name.group(1))
+    assert scoped["sort"] == {"lpa/scatter"}
+    assert scoped["reduce-window"] == {"lpa/gather"}
+    assert "lpa/scatter" in scoped["scatter"]
