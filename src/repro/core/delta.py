@@ -8,12 +8,13 @@ O(|delta| log E) on the host and O(|delta|) on the wire:
 
   * ``DeltaTracker`` -- the host-side pair ledger.  Built once per session
     graph (the one O(E) cold cost: a sorted canonical-pair key index over
-    the base edge list), it folds each ``(src, dst)`` batch through the
-    EXACT ``add_edges`` weight semantics (Eq. 3 direction counting,
-    including the reconstruction convention that a weight-1 pair stands
-    for its canonical lo->hi direction) and emits the per-batch
-    ``BatchPlan``: the symmetric weight-DELTA entries to append, the
-    per-vertex degree increments, and the endpoints whose scores changed.
+    the base edge list), it keeps each pair's two-bit arc mask
+    (``Graph.dirs``: which of lo -> hi and hi -> lo were given), folds
+    each ``(src, dst)`` batch in as ``old mask | batch mask`` -- Eq. 3
+    over the union of all arcs given, exactly as ``add_edges`` -- and
+    emits the per-batch ``BatchPlan``: the symmetric weight-DELTA entries
+    to append, the per-vertex degree increments, and the endpoints whose
+    scores changed.
     Appended entries are PARALLEL edges carrying the weight delta; the
     integer Eq. 3 weights make every scatter-add sum exact, so a layout
     holding ``(u, v, 1)`` in a base slot and ``(u, v, 1)`` in a slack slot
@@ -93,8 +94,7 @@ def check_edge_updates(src, dst, num_vertices: int,
     return src.astype(np.int32), dst.astype(np.int32)
 
 
-def coalesce_updates(batches, dedupe: bool = True
-                     ) -> Tuple[np.ndarray, np.ndarray]:
+def coalesce_updates(batches) -> Tuple[np.ndarray, np.ndarray]:
     """Fold queued ``(src, dst)`` edge-update batches into ONE batch
     whose single ``apply_delta`` is bit-identical to applying the
     batches one by one.
@@ -102,97 +102,78 @@ def coalesce_updates(batches, dedupe: bool = True
     This is the serving tier's request coalescing (``repro.serve``): N
     queued edge-update requests against one graph collapse into a single
     ``apply_delta`` plan -- one scatter, one reconvergence -- instead of
-    N.  Exactness needs care because Eq. 3's pair weights canonicalize
-    direction: ``add_edges`` (and the tracker mirroring it) stores a
-    weight-1 pair as its canonical ``lo->hi`` edge, so re-submitting the
-    SAME ``hi->lo`` edge in a LATER batch reads as the reverse direction
-    and bumps the pair to weight 2, while re-submitting ``lo->hi`` is a
-    no-op.  A plain concatenation dedupes that distinction away.
-
-    The coalesced batch therefore keeps, per canonical pair, the
-    direction(s) of the FIRST batch that contributed it, upgraded to
-    BOTH directions when any later batch re-contributes the
-    reverse-of-canonical direction.  For every prior pair weight (0, 1
-    or 2) this reproduces the sequential chain's final weight exactly,
-    so scores stay bit-identical (integer-valued f32 sums).  Self-loops
-    are dropped (they never count).  With ``dedupe=False`` the batches
-    are simply concatenated -- exact only when no pair repeats across
-    batches.
+    N.  The ledger keeps the exact arcs of every pair (Eq. 3 over their
+    union), so one batch holding the union of the arcs reaches the same
+    weights as the batches in turn: the union of the arcs, each directed
+    arc once and self-loops dropped (they never count).
     """
     batches = [b for b in batches if b is not None]
+    empty = (np.zeros(0, np.int64), np.zeros(0, np.int64))
     if not batches:
-        return (np.zeros(0, np.int32), np.zeros(0, np.int32))
-    srcs = [np.asarray(b[0]) for b in batches]
-    dsts = [np.asarray(b[1]) for b in batches]
-    if not dedupe:
-        return np.concatenate(srcs), np.concatenate(dsts)
-    nonempty = [(s, d) for s, d in zip(srcs, dsts) if s.size]
-    if not nonempty:
-        return (np.zeros(0, np.int32), np.zeros(0, np.int32))
-    base = max(int(max(s.max(), d.max())) for s, d in nonempty) + 1
-    state: dict = {}               # canonical key -> 1 canon | 2 rev | 3
-    order: list = []               # canonical keys, first-arrival order
-    for s, d in nonempty:
-        s = s.astype(np.int64)
-        d = d.astype(np.int64)
-        keep = s != d
-        s, d = s[keep], d[keep]
-        if not s.size:
-            continue
-        lo = np.minimum(s, d)
-        hi = np.maximum(s, d)
-        uniq, inv = np.unique(lo * base + hi, return_inverse=True)
-        has_c = np.zeros(uniq.size, bool)
-        has_r = np.zeros(uniq.size, bool)
-        np.logical_or.at(has_c, inv, s < d)
-        np.logical_or.at(has_r, inv, s > d)
-        for k, hc, hr in zip(uniq.tolist(), has_c.tolist(),
-                             has_r.tolist()):
-            cur = state.get(k)
-            if cur is None:
-                state[k] = (1 if hc else 0) | (2 if hr else 0)
-                order.append(k)
-            elif hr and cur != 3:  # a later reverse edge bumps w 1 -> 2
-                state[k] = 3
-    out_s: list = []
-    out_d: list = []
-    for k in order:
-        lo, hi = divmod(k, base)
-        if state[k] & 1:
-            out_s.append(lo)
-            out_d.append(hi)
-        if state[k] & 2:
-            out_s.append(hi)
-            out_d.append(lo)
-    return np.asarray(out_s, np.int64), np.asarray(out_d, np.int64)
+        return empty
+    src = np.concatenate([np.asarray(b[0]) for b in batches]).astype(np.int64)
+    dst = np.concatenate([np.asarray(b[1]) for b in batches]).astype(np.int64)
+    keep = src != dst
+    src, dst = src[keep], dst[keep]
+    if not src.size:
+        return empty
+    base = int(max(src.max(), dst.max())) + 1
+    arcs = np.unique(src * base + dst)
+    return arcs // base, arcs % base
+
+
+# What a BatchPlan counts of the ledger's view of its batch; the
+# ``delta/plan`` span and the session's ``stats()["delta"]`` carry them.
+LEDGER_COUNTS = ("arcs_given", "pairs_changed", "pairs_reciprocated",
+                 "arcs_redelivered")
+
+
+def _popcount(mask: np.ndarray) -> np.ndarray:
+    """Arcs in a two-bit pair mask: its Eq. 3 weight."""
+    return (mask & 1) + (mask >> 1)
 
 
 @dataclasses.dataclass
 class BatchPlan:
-    """One batch folded to its append-delta form (see ``DeltaTracker``)."""
+    """One batch folded to its append-delta form (see ``DeltaTracker``),
+    with what the ledger saw in it: ``arcs_given``, the pairs it took
+    from weight 1 to 2 (``pairs_reciprocated``), and the arcs that
+    changed nothing (``arcs_redelivered``: given before, or twice in the
+    batch; self-loops are neither)."""
 
     src: np.ndarray        # int32 (2 * changed_pairs,) entries to append
     dst: np.ndarray        # int32, symmetric counterparts interleaved
     dw: np.ndarray         # f32 weight DELTA carried by each entry
     touched: np.ndarray    # int32 unique endpoints of changed pairs
     pair_keys: np.ndarray  # int64 canonical keys of changed pairs
-    pair_w: np.ndarray     # f32 NEW total weight of changed pairs
+    pair_mask: np.ndarray  # uint8 NEW arc masks of changed pairs
     tw_delta: float        # total_weight change (2 * sum of pair deltas)
+    arcs_given: int = 0
+    pairs_reciprocated: int = 0
+    arcs_redelivered: int = 0
 
     @property
     def num_entries(self) -> int:
         return int(self.src.shape[0])
 
+    @property
+    def pairs_changed(self) -> int:
+        return int(self.pair_keys.shape[0])
+
 
 class DeltaTracker:
-    """Host ledger of pair weights across a session's pending deltas.
+    """Host ledger of each pair's arc mask across a session's deltas.
 
     ``plan(src, dst)`` is pure; ``commit(plan)`` folds a successfully
     merged batch into the overlay so later batches see it (sequential
-    per-batch semantics, matching a chain of ``add_edges`` calls).
+    per-batch semantics, matching a chain of ``add_edges`` calls, and
+    equal to one batch of their union).
     """
 
     def __init__(self, graph: Graph):
+        if graph.dirs is None:
+            raise ValueError("graph records no arc directions (Graph.dirs)"
+                             "; build it with from_edges to stream arcs")
         V = graph.num_vertices
         half = graph.src < graph.dst
         # graph arrays are lexsorted by (src, dst), so the canonical-half
@@ -200,56 +181,44 @@ class DeltaTracker:
         self.num_vertices = V
         self.canon_keys = (graph.src[half].astype(np.int64) * V
                            + graph.dst[half])
-        self.canon_w = graph.weight[half].astype(np.float64)
-        self.pairs: dict = {}          # canonical key -> overlaid weight
+        self.canon_mask = graph.dirs            # one mask per such pair
+        self.pairs: dict = {}          # canonical key -> overlaid mask
         self.total_weight = float(graph.total_weight)
 
-    def _current_w(self, keys: np.ndarray) -> np.ndarray:
-        w = np.zeros(keys.size, np.float64)
+    def _masks(self, keys: np.ndarray) -> np.ndarray:
+        """The arc masks of canonical pair ``keys`` (0: no arc yet)."""
+        m = np.zeros(keys.size, np.uint8)
         if self.canon_keys.size:
             pos = np.searchsorted(self.canon_keys, keys)
             pos_c = np.minimum(pos, self.canon_keys.size - 1)
             found = self.canon_keys[pos_c] == keys
-            w[found] = self.canon_w[pos_c[found]]
-        for i, key in enumerate(keys):
-            ov = self.pairs.get(int(key))
+            m[found] = self.canon_mask[pos_c[found]]
+        for i, key in enumerate(keys.tolist()):
+            ov = self.pairs.get(key)
             if ov is not None:
-                w[i] = ov
-        return w
+                m[i] = ov
+        return m
 
     def plan(self, src: np.ndarray, dst: np.ndarray) -> BatchPlan:
         V = self.num_vertices
+        arcs_given = int(src.size)
         keep = src != dst                       # self-loops never count
         src, dst = src[keep], dst[keep]
-        empty = BatchPlan(
-            src=np.zeros(0, np.int32), dst=np.zeros(0, np.int32),
-            dw=np.zeros(0, np.float32), touched=np.zeros(0, np.int32),
-            pair_keys=np.zeros(0, np.int64), pair_w=np.zeros(0, np.float32),
-            tw_delta=0.0)
-        if src.size == 0:
-            return empty
-        # dedupe directed edges within the batch (from_edges semantics)
+        # dedupe directed arcs within the batch (from_edges semantics)
         dirkey = np.unique(src.astype(np.int64) * V + dst)
         s = dirkey // V
         d = dirkey % V
-        lo = np.minimum(s, d)
-        hi = np.maximum(s, d)
-        is_canon = s < d
-        uniq, inv = np.unique(lo * V + hi, return_inverse=True)
-        has_canon = np.zeros(uniq.size, bool)
-        has_rev = np.zeros(uniq.size, bool)
-        np.logical_or.at(has_canon, inv, is_canon)
-        np.logical_or.at(has_rev, inv, ~is_canon)
-        w0 = self._current_w(uniq)
-        # add_edges reconstructs a weight-1 pair as its canonical lo->hi
-        # direction, so: canonical exists iff w0 >= 1, reverse iff w0 == 2
-        new_w = (((w0 >= 1) | has_canon).astype(np.float64)
-                 + ((w0 >= 2) | has_rev).astype(np.float64))
-        change = new_w > w0
-        if not change.any():
-            return empty
-        uniq, w0, new_w = uniq[change], w0[change], new_w[change]
-        dw_pair = (new_w - w0).astype(np.float32)
+        uniq, inv = np.unique(np.minimum(s, d) * V + np.maximum(s, d),
+                              return_inverse=True)
+        batch_mask = np.zeros(uniq.size, np.uint8)
+        np.bitwise_or.at(batch_mask, inv, np.where(s < d, 1, 2)
+                         .astype(np.uint8))
+        m0 = self._masks(uniq)
+        m1 = m0 | batch_mask
+        change = m1 != m0                       # an arc not given before
+        uniq, m1 = uniq[change], m1[change]
+        w0, w1 = _popcount(m0[change]), _popcount(m1)
+        dw_pair = (w1 - w0).astype(np.float32)
         p_lo = (uniq // V).astype(np.int32)
         p_hi = (uniq % V).astype(np.int32)
         # each changed pair appends BOTH directed entries carrying dw
@@ -259,12 +228,14 @@ class DeltaTracker:
         return BatchPlan(
             src=e_src, dst=e_dst, dw=e_dw,
             touched=np.unique(e_src).astype(np.int32),
-            pair_keys=uniq, pair_w=new_w.astype(np.float32),
-            tw_delta=float(2.0 * dw_pair.sum()))
+            pair_keys=uniq, pair_mask=m1,
+            tw_delta=float(2.0 * dw_pair.sum()), arcs_given=arcs_given,
+            pairs_reciprocated=int(np.count_nonzero((w0 == 1) & (w1 == 2))),
+            arcs_redelivered=int(src.size - (w1 - w0).sum()))
 
     def commit(self, plan: BatchPlan) -> None:
-        for key, w in zip(plan.pair_keys, plan.pair_w):
-            self.pairs[int(key)] = float(w)
+        for key, m in zip(plan.pair_keys.tolist(), plan.pair_mask.tolist()):
+            self.pairs[key] = m
         self.total_weight += plan.tw_delta
 
 
@@ -542,7 +513,9 @@ def apply_delta(tracker: DeltaTracker, dd: DeviceDelta, src, dst,
     because appended delta entries carry exact integer weight sums).
     This is the primitive a multi-tenant delta scheduler coalesces
     through: batches validated with ``check_edge_updates`` fold
-    sequentially with ``add_edges`` union semantics.
+    sequentially with ``add_edges`` union semantics.  The ``delta/plan``
+    span carries the plan's entries and what the ledger saw
+    (``LEDGER_COUNTS``).
     """
     src = np.asarray(src, np.int64)
     dst = np.asarray(dst, np.int64)
@@ -550,6 +523,7 @@ def apply_delta(tracker: DeltaTracker, dd: DeviceDelta, src, dst,
         plan = tracker.plan(src, dst)
         slotting = plan_slots(dd, plan) if plan.num_entries else None
         at["entries"] = plan.num_entries
+        at.update((c, getattr(plan, c)) for c in LEDGER_COUNTS)
     nbytes = 0
     if plan.num_entries:
         if slotting is None:

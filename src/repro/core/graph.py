@@ -25,6 +25,16 @@ class Graph:
     Every undirected edge {u, v} appears twice: once as (u, v) and once as
     (v, u), both carrying the Eq. (3) weight w(u, v) in {1, 2}.  This makes
     per-vertex aggregation a pure segment operation over ``src``.
+
+    ``dirs`` records which arcs of each pair were given, on the host only
+    (no engine uploads it): one mask per pair, in the order of the pair's
+    ``lo -> hi`` entries (``src < dst``, CSR order, which is the order of
+    the pairs' keys ``lo * V + hi``), with bit 0 the arc lo -> hi and bit
+    1 the arc hi -> lo; ``weight`` is its popcount.  An undirected input
+    pair counts as its lo -> hi arc.  ``add_edges`` and the delta ledger
+    (``repro.core.delta``) rebuild the exact arc set from it; graphs
+    built other than from arcs (a padded view's filler, a relabelled
+    copy) may leave it None.
     """
 
     num_vertices: int
@@ -33,6 +43,7 @@ class Graph:
     weight: np.ndarray     # float32 (2*E_undirected,)
     row_ptr: np.ndarray    # int64 (V+1,)  CSR offsets into src/dst/weight
     deg_w: np.ndarray      # float32 (V,)  weighted degree = sum of incident w
+    dirs: Optional[np.ndarray] = None  # uint8 (E_undirected,) arc masks
 
     @property
     def num_directed_entries(self) -> int:
@@ -91,24 +102,38 @@ def _from_edges(src, dst, num_vertices: int, directed: bool) -> Graph:
         assert int(max(src.max(), dst.max())) < num_vertices
     src, dst = _dedupe(src, dst, num_vertices)
 
-    lo = np.minimum(src, dst).astype(np.int64)
-    hi = np.maximum(src, dst).astype(np.int64)
-    canon = lo * num_vertices + hi
-    uniq, counts = np.unique(canon, return_counts=True)
+    # one sort of (pair, direction) keys gives each pair's weight and
+    # which of its arcs were given (lo * V + hi < 2**62 for V <= 2**31)
+    key = np.minimum(src, dst).astype(np.int64) * (2 * num_vertices)
+    key += np.maximum(src, dst).astype(np.int64) * 2
+    key += src > dst
+    key.sort()
+    pair = key >> 1
+    first = np.ones(pair.size + 1, bool)
+    first[1:-1] = pair[1:] != pair[:-1]
+    starts = np.flatnonzero(first[:-1])
+    both = ~first[starts + 1]                   # the pair's second arc
+    uniq = pair[starts]
     u = (uniq // num_vertices).astype(np.int32)
     v = (uniq % num_vertices).astype(np.int32)
     if directed:
-        w = counts.astype(np.float32)          # 1 = one direction, 2 = both
+        w = both.astype(np.float32) + 1         # 1 = one direction, 2 = both
+        mask = (key[starts] & 1).astype(np.uint8) + 1
+        mask[both] = 3
     else:
-        w = np.ones_like(counts, dtype=np.float32)
+        w = np.ones(uniq.size, np.float32)
+        mask = np.ones(uniq.size, np.uint8)
 
     sym_src = np.concatenate([u, v])
     sym_dst = np.concatenate([v, u])
     sym_w = np.concatenate([w, w])
-    return _finish(sym_src, sym_dst, sym_w, num_vertices)
+    return _finish(sym_src, sym_dst, sym_w, num_vertices, dirs=mask)
 
 
-def _finish(src, dst, w, num_vertices: int) -> Graph:
+def _finish(src, dst, w, num_vertices: int,
+            dirs: Optional[np.ndarray] = None) -> Graph:
+    """The CSR graph of symmetric entries; ``dirs``, where given, is
+    already in the order of the pairs' keys (see ``Graph``)."""
     order = np.lexsort((dst, src))
     src, dst, w = src[order], dst[order], w[order].astype(np.float32)
     counts = np.bincount(src, minlength=num_vertices).astype(np.int64)
@@ -118,28 +143,31 @@ def _finish(src, dst, w, num_vertices: int) -> Graph:
     np.add.at(deg_w, src, w)
     return Graph(num_vertices=num_vertices, src=src.astype(np.int32),
                  dst=dst.astype(np.int32), weight=w, row_ptr=row_ptr,
-                 deg_w=deg_w)
+                 deg_w=deg_w, dirs=dirs)
 
 
 def add_edges(graph: Graph, new_src, new_dst, directed: bool = True,
               num_vertices: Optional[int] = None) -> Graph:
     """Incremental growth (Section 3.4): returns the extended graph.
 
-    ``num_vertices`` may exceed the old count to inject new vertices.
-    Weights are recomputed for touched pairs; untouched edges keep theirs.
+    The result is ``from_edges`` over the union of the arcs ``graph`` was
+    built from (read back from ``graph.dirs``) and the new ones, so a
+    chain of calls gives the Eq. (3) weights of all arcs given so far: a
+    follow-back ``lo -> hi`` after ``hi -> lo`` makes weight 2, and an arc
+    given again changes nothing.  ``num_vertices`` may exceed the old
+    count to inject new vertices.
     """
+    if graph.dirs is None:
+        raise ValueError("graph records no arc directions (Graph.dirs); "
+                         "build it with from_edges to add arcs to it")
     V = max(num_vertices or 0, graph.num_vertices,
             int(np.max(new_src) + 1) if len(new_src) else 0,
             int(np.max(new_dst) + 1) if len(new_dst) else 0)
-    # Reconstruct a directed view of the old graph: an undirected edge of
-    # weight 2 stands for both directions, weight 1 for the canonical one.
     half = graph.src < graph.dst
-    u, v, w = graph.src[half], graph.dst[half], graph.weight[half]
-    both = w >= 2
-    old_src = np.concatenate([u, v[both]])
-    old_dst = np.concatenate([v, u[both]])
-    src = np.concatenate([old_src, np.asarray(new_src, np.int32)])
-    dst = np.concatenate([old_dst, np.asarray(new_dst, np.int32)])
+    u, v, m = graph.src[half], graph.dst[half], graph.dirs
+    fwd, rev = (m & 1) > 0, (m & 2) > 0
+    src = np.concatenate([u[fwd], v[rev], np.asarray(new_src, np.int32)])
+    dst = np.concatenate([v[fwd], u[rev], np.asarray(new_dst, np.int32)])
     return from_edges(src, dst, V, directed=directed)
 
 
@@ -211,7 +239,8 @@ def remove_vertices(graph: Graph, vertices) -> Graph:
     drop[np.asarray(vertices)] = True
     keep = ~(drop[graph.src] | drop[graph.dst])
     return _finish(graph.src[keep], graph.dst[keep], graph.weight[keep],
-                   graph.num_vertices)
+                   graph.num_vertices, None if graph.dirs is None
+                   else graph.dirs[keep[graph.src < graph.dst]])
 
 
 # ---------------------------------------------------------------------------

@@ -137,6 +137,7 @@ class PartitionSession:
         self._host_rebuilds = 0
         self._delta_bytes_last = 0
         self._delta_bytes_total = 0
+        self._ledger = dict.fromkeys(_delta.LEDGER_COUNTS, 0)
         self._score_pass: Optional[str] = None   # this call's (_note_pass)
         self._score_passes = {"transposed": 0, "forward": 0}
         self.graph = graph
@@ -171,7 +172,7 @@ class PartitionSession:
 
     def _materialize(self) -> None:
         """Fold the pending delta log into a host Graph.  One coalesced
-        ``add_edges`` call: the union-of-directions weight semantics are
+        ``add_edges`` call: Eq. 3 over the union of the arcs is
         order-independent, so batching is exact."""
         pending, self._pending = self._pending, []
         if not pending:
@@ -585,7 +586,7 @@ class PartitionSession:
         mp = _engine._merge_program()
         self._track(mp)
         dd, tracker = fs.dd, fs.tracker
-        nbytes = 0
+        nbytes, plans = 0, []
         batches = self._pending[fs.merged:] + [(e_src, e_dst)]
         for bs, bd in batches:
             out = _delta.apply_delta(tracker, dd, bs, bd, mp.run)
@@ -593,7 +594,10 @@ class PartitionSession:
                 return None          # slack overflow -> rebuild fallback
             dd, plan, b = out
             nbytes += b
+            plans.append(plan)
             self._mark_dirty(plan.touched)
+        for c in _delta.LEDGER_COUNTS:
+            self._ledger[c] += sum(getattr(p, c) for p in plans)
         self._pending.append((e_src, e_dst))
         fs.dd, fs.merged = dd, len(self._pending)
         self._delta_bytes_last = nbytes
@@ -952,10 +956,12 @@ class PartitionSession:
 
     def stats(self) -> dict:
         """Session state: shape buckets, compile/run counters, padded
-        layout, the delta fast-path counters, and (on a mesh) the
-        exchange plan's wire volumes.  Reads the BASE graph -- pending
-        fast-path deltas are reported under ``"delta"`` without forcing
-        a host materialization."""
+        layout, the delta fast-path counters (with what the pair ledger
+        saw in the batches it planned: arcs given, pairs changed, pairs
+        reciprocated from weight 1 to 2, redelivered arcs that changed
+        nothing), and (on a mesh) the exchange plan's wire volumes.
+        Reads the BASE graph -- pending fast-path deltas are reported
+        under ``"delta"`` without forcing a host materialization."""
         self._check_open()
         graph, opts = self._graph, self.options
         padded, _ = _engine.padded_view(graph, opts)
@@ -988,6 +994,7 @@ class PartitionSession:
                 "tracked_total_weight": (
                     fs.tracker.total_weight if fs is not None
                     else float(graph.total_weight)),
+                **self._ledger,
             },
         }
         ndev = (opts.mesh.shape[opts.axis] if opts.mesh is not None else 1)

@@ -9,9 +9,9 @@ Dispatch is window-based: ``Tenant.next_window`` pops the unit one device
 dispatch serves -- the longest leading run of ``edge_updates`` requests
 plus (when one immediately follows) a single plain ``adapt``.  All
 requests in a window complete with the SAME result:
-``delta.coalesce_updates`` folds the queued batches into one
-direction-aware delta that produces bit-identical labels to applying
-them one by one and reconverging once, so N queued edge-update requests
+``delta.coalesce_updates`` folds the queued batches into the union of
+their arcs, which produces bit-identical labels to applying them one by
+one and reconverging once, so N queued edge-update requests
 cost one ``apply_delta`` scatter plus one reconvergence (the coalescing
 the scheduler's ``coalescing_factor`` measures).  ``partition``/``resize`` requests -- and adapts that rebind
 to a new graph or ask for frontier reconvergence -- dispatch alone.

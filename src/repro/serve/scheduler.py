@@ -10,10 +10,10 @@ requests through three performance layers:
    round pops a tenant's leading run of queued edge-update requests (plus
    at most one trailing plain ``adapt``) as ONE window; the coalesced
    delta folds through a single ``apply_delta`` scatter and one
-   reconvergence.  ``coalesce_updates`` preserves Eq. 3's
-   direction-canonicalized pair weights exactly, so every ticket in the
-   window resolves to the same bit-identical result a one-by-one replay
-   would reach.
+   reconvergence.  The delta ledger keeps each pair's exact arcs, so
+   Eq. 3 over the union of the window's arcs -- which is all
+   ``coalesce_updates`` hands over -- gives every ticket in the window
+   the same bit-identical result a one-by-one replay would reach.
 
 2. **Same-bucket batched execution** (``engine.run_batched``): windows
    from tenants whose padded (V, E) buckets, config statics and backend

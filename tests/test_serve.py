@@ -168,24 +168,22 @@ class TestCoalescing:
         b2 = (np.array([0, 4]), np.array([2, 5]))   # (0->2) repeats
         src, dst = _delta.coalesce_updates([b1, b2])
         assert list(zip(src, dst)) == [(0, 2), (1, 3), (4, 5)]
-        src, dst = _delta.coalesce_updates([b1, b2], dedupe=False)
-        assert len(src) == 4
         src, dst = _delta.coalesce_updates([])
         assert src.size == 0 and dst.size == 0
 
     def test_coalesce_updates_direction_canonicalization(self):
-        """Eq. 3 canonicalizes weight-1 pairs to lo->hi, so a LATER
-        reverse-direction repeat bumps the pair to weight 2 -- the
-        coalesced batch must keep both directions for exactly those."""
-        rev = (np.array([2]), np.array([0]))        # reverse of canonical
-        can = (np.array([0]), np.array([2]))
-        # same reverse edge twice across batches: sequential gives w=2
+        """Eq. 3 counts each pair's given arcs, so the coalesced batch is
+        the union of the arcs: an arc repeated across batches appears
+        once, and the two directions of a pair stay two arcs (weight 2)
+        in whichever order they came."""
+        rev = (np.array([2]), np.array([0]))        # hi -> lo
+        can = (np.array([0]), np.array([2]))        # lo -> hi
+        # the same arc twice across batches: one arc, w stays 1
         src, dst = _delta.coalesce_updates([rev, rev])
-        assert sorted(zip(src, dst)) == [(0, 2), (2, 0)]
-        # reverse then canonical: the later lo->hi is a no-op, w stays 1
-        src, dst = _delta.coalesce_updates([rev, can])
         assert list(zip(src, dst)) == [(2, 0)]
-        # canonical then reverse: w=2
+        # a follow-back in either order: both arcs, w=2
+        src, dst = _delta.coalesce_updates([rev, can])
+        assert sorted(zip(src, dst)) == [(0, 2), (2, 0)]
         src, dst = _delta.coalesce_updates([can, rev])
         assert sorted(zip(src, dst)) == [(0, 2), (2, 0)]
         # canonical repeated: idempotent
