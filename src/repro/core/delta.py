@@ -262,7 +262,7 @@ class DeviceDelta:
     # --- single-device COO (and the pallas frontier COO) ---
     next_slot: int = 0         # first free tail slot of the padded COO
     e_capacity: int = 0        # total COO slots (the edge bucket)
-    csr_entries: int = 0       # single_xla: entries in CSR order (the base)
+    csr_entries: int = 0       # single_xla: real entries, in CSR order
     # --- single_pallas tiled layout ---
     tile_v: int = 0
     region: int = 0            # max_chunks * tile_e slots per tile
@@ -280,8 +280,10 @@ def init_single_xla(score_args: tuple, deg_w: jax.Array,
                     num_entries: int) -> DeviceDelta:
     """Mode A: the padded COO upload; slack = pad_graph's tail filler.
     ``score_args`` is the XLA backend's ``(src, dst, w, row_ptr,
-    merged)``; a merge writes the first three and counts its entries
-    into ``merged``, which turns the score pass to the forward one."""
+    merged)``; a merge writes the batch into the first three, sorts them
+    back into CSR order with the slack last and gives the matching
+    ``row_ptr``, so ``merged`` stays 0 and the score pass the transposed
+    one."""
     return DeviceDelta(mode="single_xla", score=tuple(score_args),
                        deg_w=deg_w, next_slot=int(num_entries),
                        e_capacity=int(score_args[0].shape[0]),
@@ -406,7 +408,9 @@ def apply_batch(dd: DeviceDelta, plan: BatchPlan, slotting,
     Returns the updated ``DeviceDelta`` (fresh jnp arrays, functional
     update) and the batch upload byte count -- O(|delta|), the transfer
     the session's ``stats()`` counters account.  The batch's uploads run
-    in the span ``delta/upload``, the merge's dispatch in ``delta/merge``.
+    in the span ``delta/upload``, the merge's dispatch in ``delta/merge``;
+    in ``single_xla`` mode the merge also re-sorts the edge list into CSR
+    order, and the span's ``csr_entries`` counts its real entries.
     """
     slots, commit = slotting
     n = plan.num_entries
@@ -414,6 +418,7 @@ def apply_batch(dd: DeviceDelta, plan: BatchPlan, slotting,
     dst32 = plan.dst.astype(np.int32)
     dw32 = plan.dw.astype(np.float32)
     host_arrays = []
+    order = ()      # single_xla: the merge's CSR re-sort (engine._csr_order)
 
     def dev(a):
         host_arrays.append(a)
@@ -430,13 +435,14 @@ def apply_batch(dd: DeviceDelta, plan: BatchPlan, slotting,
             didx = dev(_bucket_pad([(plan.src.astype(np.int64), True)], n,
                                    int(dd.deg_w.size))[0])
             add_groups = ((dd.deg_w, didx, vw),)
-            n_merged = dev(np.int32(dd.next_slot + n - dd.csr_entries))
+            fill = dd.next_slot + n
+            order = (dd.score[3], didx, dev(np.int32(fill)))
 
             def unpack(merged):
-                (new_score,), (new_deg,) = merged
+                (new_score,), (new_deg,), row_ptr = merged
                 return dataclasses.replace(
-                    dd, score=tuple(new_score) + (dd.score[3], n_merged),
-                    deg_w=new_deg)
+                    dd, score=tuple(new_score) + (row_ptr, dd.score[4]),
+                    deg_w=new_deg, csr_entries=fill)
         elif dd.mode == "single_pallas":
             tile_slots, coo_slots = slots
             sl_local = (dd.perm[plan.src] % dd.tile_v).astype(np.int32)
@@ -463,7 +469,7 @@ def apply_batch(dd: DeviceDelta, plan: BatchPlan, slotting,
                           (dd.deg_w, deg_idx, v_w))
 
             def unpack(merged):
-                (tiled3, coo2), (new_deg_t, new_deg) = merged
+                (tiled3, coo2), (new_deg_t, new_deg), _ = merged
                 return dataclasses.replace(
                     dd, score=tuple(tiled3) + dd.score[3:5] + (new_deg_t,),
                     coo=tuple(coo2), deg_w=new_deg)
@@ -482,15 +488,17 @@ def apply_batch(dd: DeviceDelta, plan: BatchPlan, slotting,
             add_groups = ((dd.deg_w, didx, v_w),)
 
             def unpack(merged):
-                (new_score,), (new_deg,) = merged
+                (new_score,), (new_deg,), _ = merged
                 return dataclasses.replace(dd, score=tuple(new_score),
                                            deg_w=new_deg)
         else:
             raise ValueError(f"unknown DeviceDelta mode {dd.mode!r}")
         nbytes = int(sum(a.nbytes for a in host_arrays))
         at["bytes"] = nbytes
-    with trace.span("delta/merge"):
-        out = unpack(merge_run(set_groups, add_groups))
+    with trace.span("delta/merge") as at:
+        out = unpack(merge_run(set_groups, add_groups, order))
+        if order:
+            at["csr_entries"] = out.csr_entries
     # commit AFTER a successful scatter but BEFORE snapshotting the host
     # slot state into the returned DeviceDelta (commit mutates dd's
     # fill/next_slot fields in place)

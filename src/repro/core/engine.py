@@ -971,9 +971,26 @@ def run_frontier(graph: Graph, cfg, labels, loads, key, active,
 # On-device delta merge programs (the adapt(edge_updates=...) fast path)
 # ---------------------------------------------------------------------------
 
+def _csr_order(src, dst, w, row_ptr, rows, fill):
+    """A padded edge list whose slots before ``fill`` are real entries,
+    back in CSR order: sorted by (row, slot at or past ``fill``), so every
+    slack slot follows every real entry even where the pads sit on the
+    last real row, with ``row_ptr`` advanced by the count of each row in
+    ``rows`` (a batch's sources; sentinel ids past the last row drop) and
+    held at E.  Rows of pad vertices keep monotone starts at or past the
+    real entries; their entries weigh 0."""
+    slack = jnp.arange(src.shape[0], dtype=src.dtype) >= fill
+    key, dst, w = jax.lax.sort((src * 2 + slack.astype(src.dtype), dst, w),
+                               num_keys=1, is_stable=False)
+    counts = jnp.zeros(row_ptr.shape, row_ptr.dtype).at[rows + 1].add(
+        1, mode="drop")
+    row_ptr = jnp.minimum(row_ptr + jnp.cumsum(counts), src.shape[0])
+    return (key >> 1, dst, w), row_ptr
+
+
 def _merge_program() -> Program:
-    """``run(set_groups, add_groups)``: scatter a delta batch into resident
-    device arrays.
+    """``run(set_groups, add_groups, order=())``: scatter a delta batch
+    into resident device arrays.
 
     ``set_groups`` is a tuple of ``(arrays, idx, vals)`` where every array
     in ``arrays`` receives ``vals[i]`` at the shared flat slots ``idx``
@@ -981,12 +998,16 @@ def _merge_program() -> Program:
     tuple of ``(array, idx, inc)`` flat scatter-adds (per-vertex degree
     updates).  Batches are shape-bucketed by the caller with
     out-of-range sentinel indices, which ``mode="drop"`` discards -- so
-    one compiled entry serves every batch in a size bucket.
+    one compiled entry serves every batch in a size bucket.  ``order``,
+    when given, is ``(row_ptr, rows, fill)`` for a first set group that
+    is a CSR edge list ``(src, dst, w)``: the group comes back re-sorted
+    into row order (``_csr_order``) and the new ``row_ptr`` is the third
+    output, else ``()``.
     """
 
     def build():
         @jax.jit
-        def run(set_groups, add_groups):
+        def run(set_groups, add_groups, order=()):
             with jax.named_scope("delta/merge"):
                 merged = tuple(
                     tuple(a.reshape(-1).at[idx].set(v, mode="drop")
@@ -995,7 +1016,11 @@ def _merge_program() -> Program:
                 bumped = tuple(
                     a.reshape(-1).at[idx].add(inc, mode="drop")
                     .reshape(a.shape) for a, idx, inc in add_groups)
-            return merged, bumped
+                row_ptr = ()
+                if order:
+                    edges, row_ptr = _csr_order(*merged[0], *order)
+                    merged = (edges,) + merged[1:]
+            return merged, bumped, row_ptr
 
         return run
 

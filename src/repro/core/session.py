@@ -772,9 +772,10 @@ class PartitionSession:
         """Note the score pass of the call's program, from the host's own
         merged-entry count (no device read): ``"transposed"`` on the XLA
         backend's single-device CSR-ordered arrays
-        (``repro.kernels.ops.xla_scores``); ``"forward"``, a label gather
-        per entry, on arrays a fast adapt merged into, on a mesh and on
-        the Pallas kernels."""
+        (``repro.kernels.ops.xla_scores``), a fast adapt's merged ones
+        included, since its merge re-sorts them into CSR order;
+        ``"forward"``, a label gather per entry, on a mesh and on the
+        Pallas kernels."""
         xla = getattr(self.options.backend(), "name", None) == "xla"
         self._score_pass = ("transposed" if xla and not sharded
                             and not merged else "forward")

@@ -226,9 +226,8 @@ def xla_scores(labels: jax.Array, src: jax.Array, dst: jax.Array,
     """The XLA backend's (V, k) scores: the transposed pass while the
     edge arrays are in CSR order (``merged == 0``), else the forward
     gather + scatter-add of ``spinner_scores_ref``.  ``merged`` counts
-    the entries a session's delta merge wrote into the slack tail out of
-    that order (``repro.core.delta``); both passes live in one program,
-    so a merge compiles nothing new."""
+    entries out of that order; a session's delta merge re-sorts what it
+    writes into CSR order (``repro.core.delta``), so it stays 0 there."""
     return jax.lax.cond(
         merged == 0,
         lambda: transposed_scores(labels, dst, w, row_ptr, k),

@@ -316,26 +316,35 @@ class TestDeltaMerge:
 
 class TestFrontierSingleDevice:
 
-    def _parity(self, g, cfg, opts, seed=3, nb=8):
+    def _parity(self, g, cfg, opts, seed=3, nb=8, rounds=1):
+        """``rounds`` fast frontier adapts in a row, each against the
+        full re-adapt of a session rebuilt on the grown graph."""
         s, r1 = _converged(g, cfg, opts)
         rng = np.random.default_rng(seed)
         V = g.num_vertices
-        b = (rng.integers(0, V, nb), rng.integers(0, V, nb))
-        rf = s.adapt(edge_updates=b, frontier=True)
-        o = open_session(add_edges(g, *b), cfg, opts)
-        ro = o.adapt(prev=r1.labels)
-        assert np.array_equal(rf.labels, ro.labels), \
-            "frontier labels diverge from the full re-adapt oracle"
-        # strictly sub-linear scored fraction, reported per iteration
-        assert rf.iterations >= 1
-        assert len(rf.scored_per_iter) == rf.iterations
-        assert rf.scored_vertices == sum(rf.scored_per_iter)
-        assert rf.scored_vertices < 0.25 * V * rf.iterations
+        for _ in range(rounds):
+            b = (rng.integers(0, V, nb), rng.integers(0, V, nb))
+            rf = s.adapt(edge_updates=b, frontier=True)
+            g = add_edges(g, *b)
+            o = open_session(g, cfg, opts)
+            ro = o.adapt(prev=r1.labels)
+            assert np.array_equal(rf.labels, ro.labels), \
+                "frontier labels diverge from the full re-adapt oracle"
+            # strictly sub-linear scored fraction, reported per iteration
+            assert rf.iterations >= 1
+            assert len(rf.scored_per_iter) == rf.iterations
+            assert rf.scored_vertices == sum(rf.scored_per_iter)
+            assert rf.scored_vertices < 0.25 * V * rf.iterations
+            r1 = rf
+        assert s.stats()["delta"]["fast_adapts"] == rounds
         return rf
 
     def test_frontier_parity_xla(self, fixed_point_graph):
+        """Two fast frontier adapts: the second expands over the edge
+        list the first merge re-sorted into CSR order."""
         cfg = SpinnerConfig(k=4, max_iters=120, seed=9, c=1.6)
-        self._parity(fixed_point_graph, cfg, EngineOptions(engine="fused"))
+        self._parity(fixed_point_graph, cfg, EngineOptions(engine="fused"),
+                     rounds=2)
 
     def test_frontier_parity_xla_fused_on(self, fixed_point_graph):
         cfg = SpinnerConfig(k=4, max_iters=121, seed=9, c=1.6)

@@ -133,6 +133,9 @@ class TestAdaptParts:
             r_ref = ref.adapt(edge_updates=d, record_history=False) \
                 if d is not None else ref.adapt(record_history=False)
             state, bind, c, opts_t = s.adapt_parts(edge_updates=d)
+            # merged arrays stay in CSR order: the lane takes the
+            # transposed branch of the score pass
+            assert int(bind.score[4]) == 0
             (out,) = _engine.run_batched([(state, bind)], c, opts_t)
             _assert_same(s.commit_adapt(out), r_ref, f"delta {d is None}")
         assert s.stats()["delta"]["fast_adapts"] == 2
@@ -257,8 +260,10 @@ class TestScheduler:
 
     def test_mixed_fleet_parity_engines_and_plans(self, rng):
         """Batched fused tenants + sharded tenants on both exchange
-        plans + a chunked tenant, all in one fleet: every ticket's
-        labels are bit-identical to direct session calls (1 device)."""
+        plans + a chunked tenant, all in one fleet, over two rounds of
+        edge updates: every ticket's labels are bit-identical to direct
+        session calls (1 device).  The batched lanes' second round scores
+        the edge lists their first merge re-sorted into CSR order."""
         from repro.launch.mesh import make_partition_mesh
         cfg = SpinnerConfig(k=4, max_iters=147, seed=6)
         mesh = make_partition_mesh(1)
@@ -273,23 +278,31 @@ class TestScheduler:
                                     label_exchange="delta")),
             "ch": (_graph(500, seed=5), EngineOptions(engine="chunked")),
         }
-        deltas = {n: _delta_batch(rng, g.num_vertices)
-                  for n, (g, _) in fleet.items()}
+        rounds = [{n: _delta_batch(rng, g.num_vertices)
+                   for n, (g, _) in fleet.items()} for _ in range(2)]
         sched = PartitionScheduler(max_batch=8, batch_min=2)
-        tks = {}
         for n, (g, opts) in fleet.items():
             sched.add_tenant(n, g, cfg, opts, partition=True)
-            tks[n] = sched.submit(n, "edge_updates", edge_updates=deltas[n])
-        assert sched.drain() == len(fleet)
-        st = sched.stats()
-        assert st["errors"] == 0, st
-        assert st["batched_dispatches"] == 1      # f1 + f2 stacked
-        assert st["serial_dispatches"] == 3       # sharded x2 + chunked
+        tks = []
+        for r, deltas in enumerate(rounds, 1):
+            tks.append({n: sched.submit(n, "edge_updates",
+                                        edge_updates=deltas[n])
+                        for n in fleet})
+            assert sched.drain() == len(fleet)
+            st = sched.stats()
+            assert st["errors"] == 0, st
+            assert st["batched_dispatches"] == r      # f1 + f2 stacked
+            assert st["serial_dispatches"] == 3 * r   # sharded x2 + chunked
         for n, (g, opts) in fleet.items():
             twin = open_session(g, cfg, opts)
             twin.partition(record_history=False)
-            ref = twin.adapt(edge_updates=deltas[n], record_history=False)
-            _assert_same(tks[n].result, ref, n)
+            for r, deltas in enumerate(rounds):
+                ref = twin.adapt(edge_updates=deltas[n],
+                                 record_history=False)
+                _assert_same(tks[r][n].result, ref, (n, r))
+            if opts is None:        # the batched lanes merged on device
+                lane = sched.tenants[n].session.stats()["delta"]
+                assert lane["fast_adapts"] == 2, (n, lane)
 
     def test_batch_min_one_forces_batched_path(self, rng):
         """batch_min=1 routes even a lone window through run_batched --

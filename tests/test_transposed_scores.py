@@ -3,8 +3,9 @@
 ``ops.transposed_scores`` takes each entry's label from its own CSR row
 instead of gathering its neighbour's; on a symmetric graph it must give
 ``ref.spinner_scores_ref``'s scores bit for bit, and the runs built on it
-the oracle's labels and iteration counts.  Arrays a session's fast adapt
-merged into leave CSR order, and there the forward pass runs instead.
+the oracle's labels and iteration counts.  A session's fast adapt sorts
+the arrays it merges into back into CSR order, so it runs the transposed
+pass too.
 """
 from __future__ import annotations
 
@@ -15,7 +16,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import engine, from_edges, generators, open_session, trace
+from repro.core import (add_edges, engine, from_edges, generators,
+                        open_session, trace)
 from repro.core.engine import EngineOptions
 from repro.core.graph import pad_graph
 from repro.core.spinner import SpinnerConfig, prepare_init
@@ -145,10 +147,10 @@ def test_session_partition_matches_forward_oracle(gen, eng):
     assert np.array_equal(a.loads, b.loads)
 
 
-def test_fast_adapt_takes_the_forward_pass():
-    """A fast adapt merges entries into the slack out of CSR order: its
-    run takes the forward branch of the same compiled program and gives
-    the rebuilt layout's result bit for bit."""
+def test_fast_adapt_takes_the_transposed_pass():
+    """A fast adapt merges entries into the slack and sorts the edge list
+    back into CSR order: its run takes the transposed branch of the same
+    compiled program and gives the rebuilt layout's result bit for bit."""
     g = generators.watts_strogatz(600, 8, 0.3, seed=6)
     cfg = SpinnerConfig(k=6, seed=13, max_iters=62)
     rng = np.random.default_rng(7)
@@ -167,12 +169,94 @@ def test_fast_adapt_takes_the_forward_pass():
         stats = fast.stats()
     (call, rebuilt) = trace.spans("session/adapt")
     assert call.attrs["fast"] is True
-    assert call.attrs["score_pass"] == "forward"
+    assert call.attrs["score_pass"] == "transposed"
     assert rebuilt.attrs["score_pass"] == "transposed"
-    assert stats["score_pass"] == {"transposed": 1, "forward": 1}
+    assert stats["score_pass"] == {"transposed": 2, "forward": 0}
     assert np.array_equal(got.labels, want.labels)
     assert got.iterations == want.iterations
     assert np.array_equal(got.loads, want.loads)
+
+
+def _arc_stream(g, rounds: int, seed: int):
+    """``rounds`` batches of arcs: new random arcs, follow-backs of
+    pairs given one way (their weight goes from 1 to 2), an arc of the
+    base graph given again, and one of the previous batch."""
+    rng = np.random.default_rng(seed)
+    v = g.num_vertices
+    half = g.src < g.dst
+    lo, hi = g.src[half], g.dst[half]
+    one_way = np.flatnonzero(g.dirs != 3)
+    given_lo_hi = g.dirs[one_way] == 1
+    back = np.stack([np.where(given_lo_hi, hi[one_way], lo[one_way]),
+                     np.where(given_lo_hi, lo[one_way], hi[one_way])], 1)
+    again = back[:, ::-1]
+    order = rng.permutation(one_way.size)
+    batches = []
+    for r in range(rounds):
+        pick = order[4 * r:4 * r + 4]
+        arcs = [rng.integers(0, v, (10, 2)), back[pick[:3]], again[pick[3:]]]
+        if batches:                     # the last batch's follow-back
+            arcs.append(np.stack(batches[-1], 1)[10:11])
+        a = np.concatenate(arcs)
+        batches.append((a[:, 0], a[:, 1]))
+    return batches
+
+
+def _pads_last(v: int, seed: int):
+    """A small-world graph whose vertex count is its own bucket, so the
+    padded layout parks its slack on the last real row."""
+    g = generators.watts_strogatz(v, 6, 0.3, seed=seed)
+    assert engine.graph_buckets(g)[0] == v
+    return g
+
+
+STREAM_GRAPHS = {
+    "pad_rows": lambda: generators.watts_strogatz(600, 8, 0.3, seed=6),
+    "pad_last": lambda: _pads_last(640, seed=8),
+}
+
+
+@pytest.mark.parametrize("name", sorted(STREAM_GRAPHS))
+def test_fast_adapts_keep_the_merged_list_in_csr_order(name):
+    """Consecutive fast adapts: after each merge the real entries
+    ``[0, next_slot)`` are sorted by row with ``row_ptr`` their host
+    recount, the slack past them weighs 0, and the adapt gives a rebuilt
+    session's labels, iterations and loads."""
+    g = STREAM_GRAPHS[name]()
+    v = g.num_vertices
+    cfg = SpinnerConfig(k=5, seed=17, max_iters=63)
+    with open_session(g, cfg) as fast:
+        prev = fast.partition(record_history=False).labels
+        want_g = g
+        for batch in _arc_stream(g, 4, seed=9):
+            trace.clear()
+            got = fast.adapt(edge_updates=batch, record_history=False)
+            (merge,) = trace.spans("delta/merge")
+            dd = fast._delta.dd
+            src, _, w, row_ptr, merged = (np.asarray(a) for a in dd.score)
+            fill = dd.next_slot
+            assert merge.attrs["csr_entries"] == dd.csr_entries == fill
+            assert int(merged) == 0
+            assert np.all(np.diff(src[:fill]) >= 0)
+            assert not np.any(w[fill:])
+            assert np.array_equal(row_ptr[:v],
+                                  np.searchsorted(src[:fill], np.arange(v)))
+            assert row_ptr[v] == (fill if fast._delta.v_pad > v
+                                  else src.size)
+            assert np.all(np.diff(row_ptr) >= 0)
+            assert row_ptr[-1] == src.size
+            want_g = add_edges(want_g, *batch)
+            with open_session(want_g, cfg) as rebuilt:
+                want = rebuilt.adapt(prev=prev, record_history=False)
+            assert np.array_equal(got.labels, want.labels)
+            assert got.iterations == want.iterations
+            assert np.array_equal(got.loads, want.loads)
+            prev = got.labels
+        stats = fast.stats()
+    assert stats["delta"]["fast_adapts"] == 4
+    assert stats["delta"]["pairs_reciprocated"] >= 12
+    assert stats["delta"]["arcs_redelivered"] >= 7
+    assert stats["score_pass"] == {"transposed": 5, "forward": 0}
 
 
 def _gathers(jaxpr, size: int, in_forward: bool = False):
